@@ -4,11 +4,12 @@
     memory term     = traffic_bytes_per_device / HBM_bw
     collective term = collective_bytes_per_device / (links × link_bw)
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per direction), 4 ICI links per chip on a 2D torus (we budget traffic
-against one link: conservative).  Also reports MODEL_FLOPS = 6·N·D (dense)
-or 6·N_active·D (MoE) — fwd-only terms (2·N·D) for the frozen duplex
-backbone — and the useful-compute ratio MODEL_FLOPS / HLO_FLOPs.
+Hardware constants come from ``PEAKS``, keyed by the device kind JAX
+reports; the dry-run models a TPU v5e pod (``launch/mesh.py``).  Collective
+traffic is budgeted against one ICI link (conservative).  Also reports
+MODEL_FLOPS = 6·N·D (dense) or 6·N_active·D (MoE) — fwd-only terms (2·N·D)
+for the frozen duplex backbone — and the useful-compute ratio
+MODEL_FLOPS / HLO_FLOPs.
 """
 from __future__ import annotations
 
@@ -19,9 +20,21 @@ from repro.configs.common import SHAPES
 from repro.models import registry
 from repro.utils import count_params
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s
-LINK_BW = 50e9               # bytes/s per ICI link
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of
+# ICI over 4 links, i.e. 50 GB/s per link and direction).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip; an unknown kind is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def param_counts(arch: str) -> dict:
@@ -66,13 +79,14 @@ def load_cells(dryrun_dir: str = "experiments/dryrun") -> list[dict]:
 
 
 def roofline_row(rec: dict, counts: dict) -> dict:
+    peak = peaks(DRYRUN_DEVICE_KIND)
     n_dev = rec["n_devices"]
     flops_dev = rec["cost"]["dot_flops"]          # already per device (SPMD)
     traffic_dev = rec["cost"]["traffic_bytes"]
     coll_dev = rec["collectives"].get("total", 0)
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = traffic_dev / HBM_BW
-    t_coll = coll_dev / LINK_BW
+    t_compute = flops_dev / peak["flops"]
+    t_memory = traffic_dev / peak["hbm_bw"]
+    t_coll = coll_dev / peak["link_bw"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     bottleneck = max(terms, key=terms.get)
     mflops = model_flops(rec["arch"], rec["shape"], counts)
@@ -89,7 +103,8 @@ def roofline_row(rec: dict, counts: dict) -> dict:
         "compute_bound_fraction": (t_compute / max(terms.values())
                                    if max(terms.values()) > 0 else 0.0),
         # useful-model-FLOP/s at the bound, as a fraction of peak — §Perf score
-        "roofline_fraction": (mflops / n_dev / max(terms.values()) / PEAK_FLOPS
+        "roofline_fraction": (mflops / n_dev / max(terms.values())
+                              / peak["flops"]
                               if max(terms.values()) > 0 else 0.0),
         "temp_gib": rec["memory"]["temp_bytes"] / 2**30,
         "args_gib": rec["memory"]["argument_bytes"] / 2**30,

@@ -23,7 +23,7 @@ from repro.kernels.bfp_common import dequant_block, quant_block
 def _quant_kernel(x_ref, mant_ref, exp_ref, *, g, mbits, ebits):
     mant, exp = quant_block(x_ref[...], g, mbits, ebits)
     mant_ref[...] = mant
-    exp_ref[...] = exp
+    exp_ref[...] = exp.astype(exp_ref.dtype)
 
 
 @functools.partial(
@@ -48,23 +48,26 @@ def bfp_quantize_pallas(
     mp, np_ = _ceil(m, bm), _ceil(n, bn)
     x = jnp.pad(x.astype(jnp.float32), ((0, mp - m), (0, np_ - n)))
 
-    mant, exp = pl.pallas_call(
+    # exponents leave the kernel in row layout, one (bm/g, bn) slab per
+    # block row: each block then spans the slab's full second-minor dim, as
+    # the (8,128) tiling rule requires, whatever bm/g is
+    mant, exp_rows = pl.pallas_call(
         functools.partial(_quant_kernel, g=group, mbits=mbits, ebits=ebits),
         grid=(mp // bm, np_ // bn),
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm // group, bn // group), lambda i, j: (i, j)),
+            pl.BlockSpec((None, bm // group, bn), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((mp, np_), jnp.int8),
-            jax.ShapeDtypeStruct((mp // group, np_ // group), jnp.int8),
+            jax.ShapeDtypeStruct((mp // bm, bm // group, np_), jnp.int8),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x)
-    return mant, exp
+    return mant, exp_rows.reshape(mp // group, np_)[:, ::group]
 
 
 def _packed_matmul_kernel(am_ref, ae_ref, bm_ref, be_ref, o_ref, acc_ref,
@@ -116,16 +119,21 @@ def bfp_matmul_packed(
     if m % bm or n % bn or k % bk:
         raise ValueError(f"dims {(m, k, n)} must tile by blocks {(bm, bk, bn)}")
 
-    gspec = lambda d1, d2, idx: pl.BlockSpec((d1 // group, d2 // group), idx)
+    # packed (R/g, C/g) exponents → row layout (R/bR, bR/g, C): see bfp_common
+    def rows(exp, br):
+        r, c = exp.shape
+        return jnp.repeat(exp, group, axis=1).reshape(r * group // br,
+                                                      br // group, c * group)
+
     grid = (m // bm, n // bn, k // bk)
     out = pl.pallas_call(
         functools.partial(_packed_matmul_kernel, g=group, mbits=mbits),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            gspec(bm, bk, lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((None, bm // group, bk), lambda i, j, kk: (i, 0, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            gspec(bk, bn, lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((None, bk // group, bn), lambda i, j, kk: (kk, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
@@ -133,7 +141,7 @@ def bfp_matmul_packed(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a_mant, a_exp, b_mant, b_exp)
+    )(a_mant, rows(a_exp, bm), b_mant, rows(b_exp, bk))
     return out
 
 

@@ -19,10 +19,6 @@ from repro.kernels.bfp_matmul import bfp_matmul
 from repro.kernels.bfp_quant import bfp_matmul_packed, bfp_quantize_pallas
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @dataclasses.dataclass(frozen=True)
 class BFPKernelConfig:
     group: int = 32
@@ -31,25 +27,21 @@ class BFPKernelConfig:
     block_m: int = 256
     block_n: int = 256
     block_k: int = 256
-    # None → interpret automatically off on TPU, on elsewhere (CPU validation).
-    interpret: bool | None = None
-
-    @property
-    def run_interpret(self) -> bool:
-        return (not on_tpu()) if self.interpret is None else self.interpret
+    # the Pallas interpreter (CPU validation); never chosen implicitly
+    interpret: bool = False
 
 
 def matmul(a: jax.Array, b: jax.Array, cfg: BFPKernelConfig = BFPKernelConfig()):
     return bfp_matmul(
         a, b, group=cfg.group, mbits=cfg.mbits, ebits=cfg.ebits,
         block_m=cfg.block_m, block_n=cfg.block_n, block_k=cfg.block_k,
-        interpret=cfg.run_interpret)
+        interpret=cfg.interpret)
 
 
 def quantize(x: jax.Array, cfg: BFPKernelConfig = BFPKernelConfig()):
     return bfp_quantize_pallas(
         x, group=cfg.group, mbits=cfg.mbits, ebits=cfg.ebits,
-        block_m=cfg.block_m, block_n=cfg.block_n, interpret=cfg.run_interpret)
+        block_m=cfg.block_m, block_n=cfg.block_n, interpret=cfg.interpret)
 
 
 def matmul_packed(a_mant, a_exp, b_mant, b_exp,
@@ -57,7 +49,7 @@ def matmul_packed(a_mant, a_exp, b_mant, b_exp,
     return bfp_matmul_packed(
         a_mant, a_exp, b_mant, b_exp, group=cfg.group, mbits=cfg.mbits,
         block_m=cfg.block_m, block_n=cfg.block_n, block_k=cfg.block_k,
-        interpret=cfg.run_interpret)
+        interpret=cfg.interpret)
 
 
 # --------------------------------------------------------------------------

@@ -1,7 +1,6 @@
 """Cell construction shared by the dry-run, launchers, and benchmarks.
 
-Importing this module never mutates XLA flags or jax device state (unlike
-``launch.dryrun``, whose first import line forces 512 host devices).
+Importing this module never mutates XLA flags or jax device state.
 """
 from __future__ import annotations
 

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this builds the real step function (duplex train_step /
@@ -9,7 +6,8 @@ NamedShardings from ``distributed.sharding``; lowers, compiles, and records
 ``memory_analysis()`` / ``cost_analysis()`` / HLO collective traffic to a
 JSON file that §Dry-run / §Roofline read.
 
-One cell per process (jax locks the device count at first init; fresh
+One cell per process (jax locks the device count at first init, so
+``main`` sets 512 host devices before JAX's first backend use; fresh
 processes also keep compile memory bounded):
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-72b \
@@ -17,6 +15,7 @@ processes also keep compile memory bounded):
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -108,6 +107,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
 
 
 def main():
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(registry.ARCHS))
     ap.add_argument("--shape", required=True, choices=sorted(SHAPES))

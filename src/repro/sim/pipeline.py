@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import multiprocessing as mp
 import os
 import time
 from concurrent.futures import ProcessPoolExecutor, as_completed
@@ -708,7 +709,10 @@ def sweep(arms: Sequence[Arm], pipeline: Optional[Pipeline] = None, *,
     t0 = time.perf_counter()
     workers = (os.cpu_count() or 1) if parallel is True else int(parallel or 0)
     if workers > 1 and len(jobs) > 1:
-        with ProcessPoolExecutor(max_workers=min(workers, len(jobs))) as ex:
+        # spawn: a forked child would inherit a parent that may already
+        # hold the accelerator through JAX
+        with ProcessPoolExecutor(max_workers=min(workers, len(jobs)),
+                                 mp_context=mp.get_context("spawn")) as ex:
             if progress is None:
                 return list(ex.map(_sweep_one, jobs))
             futs = {ex.submit(_sweep_one, j): i for i, j in enumerate(jobs)}

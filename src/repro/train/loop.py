@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import numpy as np
@@ -42,6 +42,7 @@ class LoopReport:
     metrics_history: list
     straggler_strikes: int
     wall_s: float
+    state: Any                     # the state after the last step
 
 
 def run(loop_cfg: LoopConfig, data_cfg: DataConfig, train_step: Callable,
@@ -103,4 +104,5 @@ def run(loop_cfg: LoopConfig, data_cfg: DataConfig, train_step: Callable,
         metrics_history=history,
         straggler_strikes=strikes,
         wall_s=time.time() - t_loop,
+        state=state,
     )

@@ -157,12 +157,8 @@ def test_compressed_psum_matches_mean():
     x = jax.random.normal(jax.random.PRNGKey(2), (128,))
 
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map            # jax >= 0.5
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-    f = shard_map(lambda v: compress.compressed_psum(v, "d"),
-                  mesh=mesh, in_specs=P(), out_specs=P())
+    f = jax.shard_map(lambda v: compress.compressed_psum(v, "d"),
+                      mesh=mesh, in_specs=P(), out_specs=P())
     y = f(x)
     rel = float(jnp.linalg.norm(x - y) / jnp.linalg.norm(x))
     assert rel < 0.01

@@ -11,23 +11,14 @@ from repro.models import registry
 # (the real meshes are exercised by launch/dryrun.py).
 
 
-def _abstract_mesh(sizes, names):
-    # jax <= 0.4.x takes ((name, size), ...) pairs; newer jax takes
-    # (sizes, names) positionally
-    try:
-        return jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
-
-
 @pytest.fixture(scope="module")
 def mesh():
-    return _abstract_mesh((16, 16), ("data", "model"))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
 def multipod():
-    return _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_attention_weights_tp(mesh):
