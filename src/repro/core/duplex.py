@@ -154,6 +154,7 @@ def duplex_init(key: jax.Array, cfg: DuplexConfig, d_model: int) -> dict:
     }
 
 
+@jax.named_scope("branch")
 def duplex_apply(
     params: dict,
     cfg: DuplexConfig,

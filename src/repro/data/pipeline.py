@@ -10,10 +10,13 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+import time
 from pathlib import Path
 from typing import Iterator, Optional
 
 import numpy as np
+
+from repro.obs import runtime
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,7 +97,12 @@ def make_source(cfg: DataConfig, host_id: int = 0, n_hosts: int = 1):
 
 
 class Prefetcher:
-    """Background-thread prefetch (decouples host data prep from steps)."""
+    """Background-thread prefetch (decouples host data prep from steps).
+
+    Counts into ``repro.obs.runtime.data``: ``prefetch_wait_s``, the
+    seconds each ``next`` blocked on the queue, and ``prefetch_produce_s``,
+    the seconds the producer took for each batch it queued.
+    """
 
     def __init__(self, source, start_index: int = 0, depth: int = 2):
         self._q: queue.Queue = queue.Queue(maxsize=depth)
@@ -107,14 +115,22 @@ class Prefetcher:
     def _run(self):
         i = self._index
         while not self._stop.is_set():
+            t0 = time.perf_counter()
+            batch = self._source.batch(i)
+            made = time.perf_counter() - t0
             try:
-                self._q.put(self._source.batch(i), timeout=0.2)
-                i += 1
+                self._q.put(batch, timeout=0.2)
             except queue.Full:
                 continue
+            runtime.data.counter("prefetch_produce_s", time.time_ns(), made)
+            i += 1
 
     def next(self) -> dict:
-        return self._q.get()
+        t0 = time.perf_counter()
+        batch = self._q.get()
+        runtime.data.counter("prefetch_wait_s", time.time_ns(),
+                             time.perf_counter() - t0)
+        return batch
 
     def close(self):
         self._stop.set()

@@ -110,8 +110,9 @@ def serve(argv: list[str] | None = None) -> dict:
         t_first_decode = time.perf_counter() - t0
         toks.append(tok)
         t0 = time.perf_counter()
-        for _ in range(args.gen - 2):
-            tok, cache = decode(params, cache, tok)
+        for i in range(args.gen - 2):
+            with jax.profiler.StepTraceAnnotation("decode", step_num=i):
+                tok, cache = decode(params, cache, tok)
             toks.append(tok)
         jax.block_until_ready(tok)
         decode_s = time.perf_counter() - t0

@@ -111,6 +111,7 @@ def _rg_lru(params, x: jax.Array, policy: L.Policy, h0=None,
     return y, y[:, -1]
 
 
+@jax.named_scope("lru")
 def lru_block(params, x: jax.Array, cfg: LRUConfig, *,
               policy: L.Policy = L.Policy(), bfp: L.BFPPolicy = L.NO_BFP,
               state: dict | None = None):

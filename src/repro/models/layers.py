@@ -405,10 +405,11 @@ def attention_decode(p, x, cache: dict, cfg: AttnConfig, *, policy=Policy()):
     cur = cache["len"]
     positions = jnp.full((b, 1), cur, dtype=jnp.int32)
     q, k, v = _project_qkv(p, x, x, cfg, policy, NO_BFP, positions)
-    k_cache = lax.dynamic_update_slice_in_dim(
-        cache["k"], k.astype(cache["k"].dtype), cur, axis=1)
-    v_cache = lax.dynamic_update_slice_in_dim(
-        cache["v"], v.astype(cache["v"].dtype), cur, axis=1)
+    with jax.named_scope("kv_cache"):
+        k_cache = lax.dynamic_update_slice_in_dim(
+            cache["k"], k.astype(cache["k"].dtype), cur, axis=1)
+        v_cache = lax.dynamic_update_slice_in_dim(
+            cache["v"], v.astype(cache["v"].dtype), cur, axis=1)
     o = decode_attention(q, k_cache, v_cache, cur + 1, softcap=cfg.softcap,
                          window=cfg.window)
     o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)

@@ -65,51 +65,57 @@ def moe_apply(params, x: jax.Array, cfg: MoEConfig, *,
     xg = xt.reshape(tp // g, g, d)                     # [G,g,D]
     n_groups = tp // g
 
-    logits = L.dense(params["router"], xg, policy=policy).astype(jnp.float32)
-    gates = jax.nn.softmax(logits, axis=-1)            # [G,g,E]
+    with jax.named_scope("moe_router"):
+        logits = L.dense(params["router"], xg, policy=policy).astype(
+            jnp.float32)
+        gates = jax.nn.softmax(logits, axis=-1)        # [G,g,E]
 
-    # load-balancing aux loss (Switch/GShard): E · Σ_e f_e · P_e
-    density = jnp.mean(gates, axis=1)                  # [G,E] mean router prob
-    top1 = jax.nn.one_hot(jnp.argmax(gates, -1), cfg.n_experts)
-    frac = jnp.mean(top1, axis=1)                      # [G,E] token fraction
-    aux = cfg.n_experts * jnp.mean(jnp.sum(density * frac, axis=-1))
+        # load-balancing aux loss (Switch/GShard): E · Σ_e f_e · P_e
+        density = jnp.mean(gates, axis=1)              # [G,E] mean router prob
+        top1 = jax.nn.one_hot(jnp.argmax(gates, -1), cfg.n_experts)
+        frac = jnp.mean(top1, axis=1)                  # [G,E] token fraction
+        aux = cfg.n_experts * jnp.mean(jnp.sum(density * frac, axis=-1))
 
-    cap = capacity(cfg, g)
-    remaining = gates
-    counts = jnp.zeros((n_groups, 1, cfg.n_experts), jnp.float32)
-    dispatch = jnp.zeros((n_groups, g, cfg.n_experts, cap), cd)
-    combine = jnp.zeros((n_groups, g, cfg.n_experts, cap), cd)
-    for _ in range(cfg.top_k):
-        idx = jnp.argmax(remaining, axis=-1)           # [G,g]
-        gate_k = jnp.take_along_axis(remaining, idx[..., None], -1)[..., 0]
-        onehot = jax.nn.one_hot(idx, cfg.n_experts, dtype=jnp.float32)
-        # position of each token within its expert's capacity buffer
-        pos = jnp.cumsum(onehot, axis=1) - 1.0 + counts  # [G,g,E]
-        counts = counts + jnp.sum(onehot, axis=1, keepdims=True)
-        keep = (pos < cap) & (onehot > 0)
-        pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=jnp.float32)
-        d_k = (pos_oh * keep[..., None]).astype(cd)    # [G,g,E,C]
-        dispatch = dispatch + d_k
-        combine = combine + d_k * gate_k[..., None, None].astype(cd)
-        remaining = remaining * (1.0 - onehot)
+        cap = capacity(cfg, g)
+        remaining = gates
+        counts = jnp.zeros((n_groups, 1, cfg.n_experts), jnp.float32)
+        dispatch = jnp.zeros((n_groups, g, cfg.n_experts, cap), cd)
+        combine = jnp.zeros((n_groups, g, cfg.n_experts, cap), cd)
+        for _ in range(cfg.top_k):
+            idx = jnp.argmax(remaining, axis=-1)       # [G,g]
+            gate_k = jnp.take_along_axis(remaining, idx[..., None], -1)[..., 0]
+            onehot = jax.nn.one_hot(idx, cfg.n_experts, dtype=jnp.float32)
+            # position of each token within its expert's capacity buffer
+            pos = jnp.cumsum(onehot, axis=1) - 1.0 + counts  # [G,g,E]
+            counts = counts + jnp.sum(onehot, axis=1, keepdims=True)
+            keep = (pos < cap) & (onehot > 0)
+            pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap,
+                                    dtype=jnp.float32)
+            d_k = (pos_oh * keep[..., None]).astype(cd)  # [G,g,E,C]
+            dispatch = dispatch + d_k
+            combine = combine + d_k * gate_k[..., None, None].astype(cd)
+            remaining = remaining * (1.0 - onehot)
 
-    # normalize the kept top-k gates to sum to 1 per token
-    denom = jnp.sum(combine, axis=(-2, -1), keepdims=True)
-    combine = combine / jnp.maximum(denom, 1e-9)
+        # normalize the kept top-k gates to sum to 1 per token
+        denom = jnp.sum(combine, axis=(-2, -1), keepdims=True)
+        combine = combine / jnp.maximum(denom, 1e-9)
 
-    xe = jnp.einsum("gsec,gsd->egcd", dispatch, xg.astype(cd))  # [E,G,C,D]
-    wi = bfp.q(params["wi"]).astype(cd)
-    wo = bfp.q(params["wo"]).astype(cd)
-    h = jnp.einsum("egcd,edf->egcf", xe, wi)
-    if "wg" in params:
-        wg = bfp.q(params["wg"]).astype(cd)
-        h = jax.nn.silu(jnp.einsum("egcd,edf->egcf", xe, wg)) * h
-    else:
-        h = jax.nn.silu(h)
-    ye = jnp.einsum("egcf,efd->egcd", h, wo)            # [E,G,C,D]
-    y = jnp.einsum("gsec,egcd->gsd", combine, ye)       # [G,g,D]
-
-    y = y.reshape(tp, d)[:t].reshape(b, s, d)
+    with jax.named_scope("moe_dispatch"):
+        xe = jnp.einsum("gsec,gsd->egcd", dispatch, xg.astype(cd))  # [E,G,C,D]
+    with jax.named_scope("moe_experts"):
+        wi = bfp.q(params["wi"]).astype(cd)
+        wo = bfp.q(params["wo"]).astype(cd)
+        h = jnp.einsum("egcd,edf->egcf", xe, wi)
+        if "wg" in params:
+            wg = bfp.q(params["wg"]).astype(cd)
+            h = jax.nn.silu(jnp.einsum("egcd,edf->egcf", xe, wg)) * h
+        else:
+            h = jax.nn.silu(h)
+        ye = jnp.einsum("egcf,efd->egcd", h, wo)        # [E,G,C,D]
+    with jax.named_scope("moe_combine"):
+        y = jnp.einsum("gsec,egcd->gsd", combine, ye)   # [G,g,D]
+        y = y.reshape(tp, d)[:t].reshape(b, s, d)
     if "shared" in params:
-        y = y + L.mlp(params["shared"], x, policy=policy, bfp=bfp)
+        with jax.named_scope("mlp"):
+            y = y + L.mlp(params["shared"], x, policy=policy, bfp=bfp)
     return y.astype(x.dtype), aux

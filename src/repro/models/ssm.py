@@ -157,6 +157,7 @@ def _ssd_chunked(x, dt, A, B, C, chunk: int, h0=None):
     return y, h_fin
 
 
+@jax.named_scope("ssd")
 def ssd_block(params, x: jax.Array, cfg: SSDConfig, *,
               policy: L.Policy = L.Policy(), bfp: L.BFPPolicy = L.NO_BFP,
               state: dict | None = None):

@@ -134,7 +134,8 @@ def _apply_mlp(p, h, spec, cfg, policy, bfp):
         return h, aux
     u = _norm(cfg, p["mlp_norm"], h)
     if spec.mlp == "dense":
-        y = L.mlp(p["mlp"], u, policy=policy, bfp=bfp, act=_act(cfg))
+        with jax.named_scope("mlp"):
+            y = L.mlp(p["mlp"], u, policy=policy, bfp=bfp, act=_act(cfg))
     else:
         y, aux = moe_mod.moe_apply(p["moe"], u, _moe_cfg(cfg), policy=policy,
                                    bfp=bfp)
@@ -149,8 +150,9 @@ def _sub_apply(p, h, spec, cfg, *, policy, bfp, cross_kv, positions):
     if spec.kind in ("attn", "local", "cross"):
         u = _norm(cfg, p["norm"], h)
         kv = cross_kv if spec.kind == "cross" else None
-        y = L.attention_layer(p["attn"], u, acfg, policy=policy, bfp=bfp,
-                              kv_x=kv, positions=positions)
+        with jax.named_scope("attention"):
+            y = L.attention_layer(p["attn"], u, acfg, policy=policy, bfp=bfp,
+                                  kv_x=kv, positions=positions)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
         h = h + y
@@ -192,6 +194,7 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
     return params
 
 
+@jax.named_scope("embed")
 def embed_tokens(params, cfg: ModelConfig, tokens: jax.Array,
                  positions: jax.Array, policy: L.Policy) -> jax.Array:
     h = L.embed_lookup(params["embed"], tokens, policy)
@@ -343,59 +346,63 @@ def _sub_prefill(p, h, spec, cfg, *, policy, cross_kv, positions, max_len,
     b, s, _ = h.shape
     if spec.kind in ("attn", "local"):
         u = _norm(cfg, p["norm"], h)
-        q, k, v = L._project_qkv(p["attn"], u, u, acfg, policy, L.NO_BFP,
-                                 positions)
-        if s > acfg.blockwise_threshold:
-            o = L.blockwise_attention(q, k, v, causal=acfg.causal,
-                                      softcap=acfg.softcap, window=acfg.window,
-                                      q_chunk=acfg.q_chunk,
-                                      kv_chunk=acfg.kv_chunk,
-                                      causal_skip=acfg.causal_skip)
-        else:
-            o = L.full_attention(q, k, v, causal=acfg.causal,
-                                 softcap=acfg.softcap, window=acfg.window)
-        o = o.reshape(b, s, acfg.n_heads * acfg.head_dim)
-        y = L.dense(p["attn"]["wo"], o, policy=policy)
+        with jax.named_scope("attention"):
+            q, k, v = L._project_qkv(p["attn"], u, u, acfg, policy, L.NO_BFP,
+                                     positions)
+            if s > acfg.blockwise_threshold:
+                o = L.blockwise_attention(q, k, v, causal=acfg.causal,
+                                          softcap=acfg.softcap,
+                                          window=acfg.window,
+                                          q_chunk=acfg.q_chunk,
+                                          kv_chunk=acfg.kv_chunk,
+                                          causal_skip=acfg.causal_skip)
+            else:
+                o = L.full_attention(q, k, v, causal=acfg.causal,
+                                     softcap=acfg.softcap, window=acfg.window)
+            o = o.reshape(b, s, acfg.n_heads * acfg.head_dim)
+            y = L.dense(p["attn"]["wo"], o, policy=policy)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
         h = h + y
         size = _ring_size(cfg, spec, max_len)
-        if spec.kind == "local" and size < max_len:
-            keep = min(size, s)
-            idx = (jnp.arange(s - keep, s) % size)
-            kc = jnp.zeros((b, size, cfg.n_kv, cfg.head_dim), dtype)
-            vc = jnp.zeros_like(kc)
-            kc = kc.at[:, idx].set(k[:, -keep:].astype(dtype))
-            vc = vc.at[:, idx].set(v[:, -keep:].astype(dtype))
-            pos = jnp.full((size,), -1, jnp.int32).at[idx].set(
-                jnp.arange(s - keep, s))
-            cache = {"k": kc, "v": vc, "len": jnp.asarray(s, jnp.int32),
-                     "pos": pos}
-        else:
-            kc = jnp.zeros((b, max_len, cfg.n_kv, cfg.head_dim), dtype)
-            vc = jnp.zeros_like(kc)
-            kc = lax.dynamic_update_slice_in_dim(kc, k.astype(dtype), 0, 1)
-            vc = lax.dynamic_update_slice_in_dim(vc, v.astype(dtype), 0, 1)
-            cache = {"k": kc, "v": vc, "len": jnp.asarray(s, jnp.int32)}
+        with jax.named_scope("kv_cache"):
+            if spec.kind == "local" and size < max_len:
+                keep = min(size, s)
+                idx = (jnp.arange(s - keep, s) % size)
+                kc = jnp.zeros((b, size, cfg.n_kv, cfg.head_dim), dtype)
+                vc = jnp.zeros_like(kc)
+                kc = kc.at[:, idx].set(k[:, -keep:].astype(dtype))
+                vc = vc.at[:, idx].set(v[:, -keep:].astype(dtype))
+                pos = jnp.full((size,), -1, jnp.int32).at[idx].set(
+                    jnp.arange(s - keep, s))
+                cache = {"k": kc, "v": vc, "len": jnp.asarray(s, jnp.int32),
+                         "pos": pos}
+            else:
+                kc = jnp.zeros((b, max_len, cfg.n_kv, cfg.head_dim), dtype)
+                vc = jnp.zeros_like(kc)
+                kc = lax.dynamic_update_slice_in_dim(kc, k.astype(dtype), 0, 1)
+                vc = lax.dynamic_update_slice_in_dim(vc, v.astype(dtype), 0, 1)
+                cache = {"k": kc, "v": vc, "len": jnp.asarray(s, jnp.int32)}
         h, _ = _apply_mlp(p, h, spec, cfg, policy, L.NO_BFP)
         return h, cache
 
     if spec.kind == "cross":
         u = _norm(cfg, p["norm"], h)
-        y = L.attention_layer(p["attn"], u, acfg, policy=policy, kv_x=cross_kv,
-                              positions=positions)
+        with jax.named_scope("attention"):
+            y = L.attention_layer(p["attn"], u, acfg, policy=policy,
+                                  kv_x=cross_kv, positions=positions)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
         h = h + y
         skv = cross_kv.shape[1]
-        k = L.dense(p["attn"]["wk"], cross_kv, policy=policy).reshape(
-            b, skv, cfg.n_kv, cfg.head_dim)
-        v = L.dense(p["attn"]["wv"], cross_kv, policy=policy).reshape(
-            b, skv, cfg.n_kv, cfg.head_dim)
-        cache = {"k": k.astype(dtype), "v": v.astype(dtype)}
+        with jax.named_scope("kv_cache"):
+            k = L.dense(p["attn"]["wk"], cross_kv, policy=policy).reshape(
+                b, skv, cfg.n_kv, cfg.head_dim)
+            v = L.dense(p["attn"]["wv"], cross_kv, policy=policy).reshape(
+                b, skv, cfg.n_kv, cfg.head_dim)
+            cache = {"k": k.astype(dtype), "v": v.astype(dtype)}
         h, _ = _apply_mlp(p, h, spec, cfg, policy, L.NO_BFP)
         return h, cache
-
     if spec.kind == "ssd":
         u = _norm(cfg, p["norm"], h)
         c = _ssd_cfg(cfg)
@@ -458,7 +465,8 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array, *,
         cache["step"] = jnp.asarray(s, jnp.int32)
     h = _norm(cfg, params["final_norm"], h)
     h_out = h[:, -1:] if logits_mode == "last" else h
-    logits = lm_logits(params, cfg, h_out, policy)
+    with jax.named_scope("unembed_loss"):
+        logits = lm_logits(params, cfg, h_out, policy)
     return {"logits": logits, "cache": cache, "hidden": h}
 
 
@@ -468,11 +476,13 @@ def _sub_decode(p, h, spec, cfg, cache, *, policy):
     b = h.shape[0]
     if spec.kind in ("attn", "local"):
         u = _norm(cfg, p["norm"], h)
-        if spec.kind == "local" and "pos" in cache:
-            y, new_cache = _ring_decode(p["attn"], u, cache, acfg, cfg, policy)
-        else:
-            y, new_cache = L.attention_decode(p["attn"], u, cache, acfg,
-                                              policy=policy)
+        with jax.named_scope("attention"):
+            if spec.kind == "local" and "pos" in cache:
+                y, new_cache = _ring_decode(p["attn"], u, cache, acfg, cfg,
+                                            policy)
+            else:
+                y, new_cache = L.attention_decode(p["attn"], u, cache, acfg,
+                                                  policy=policy)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
         h = h + y
@@ -480,12 +490,14 @@ def _sub_decode(p, h, spec, cfg, cache, *, policy):
         return h, new_cache
     if spec.kind == "cross":
         u = _norm(cfg, p["norm"], h)
-        q = L.dense(p["attn"]["wq"], u, policy=policy).reshape(
-            b, 1, cfg.n_heads, cfg.head_dim)
-        o = L.full_attention(q, cache["k"], cache["v"], causal=False,
-                             softcap=acfg.softcap)
-        y = L.dense(p["attn"]["wo"],
-                    o.reshape(b, 1, cfg.n_heads * cfg.head_dim), policy=policy)
+        with jax.named_scope("attention"):
+            q = L.dense(p["attn"]["wq"], u, policy=policy).reshape(
+                b, 1, cfg.n_heads, cfg.head_dim)
+            o = L.full_attention(q, cache["k"], cache["v"], causal=False,
+                                 softcap=acfg.softcap)
+            y = L.dense(p["attn"]["wo"],
+                        o.reshape(b, 1, cfg.n_heads * cfg.head_dim),
+                        policy=policy)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
         h = h + y
@@ -517,12 +529,13 @@ def _ring_decode(p_attn, u, cache, acfg: L.AttnConfig, cfg: ModelConfig,
     positions = jnp.full((b, 1), cur, jnp.int32)
     q, k, v = L._project_qkv(p_attn, u, u, acfg, policy, L.NO_BFP, positions)
     slot = cur % size
-    kc = lax.dynamic_update_slice_in_dim(
-        cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-    vc = lax.dynamic_update_slice_in_dim(
-        cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
-    pos = lax.dynamic_update_slice_in_dim(
-        cache["pos"], cur[None].astype(jnp.int32), slot, axis=0)
+    with jax.named_scope("kv_cache"):
+        kc = lax.dynamic_update_slice_in_dim(
+            cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
+        vc = lax.dynamic_update_slice_in_dim(
+            cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
+        pos = lax.dynamic_update_slice_in_dim(
+            cache["pos"], cur[None].astype(jnp.int32), slot, axis=0)
     g = acfg.n_heads // acfg.n_kv
     scores = L._softcap(
         L._gqa_scores(q, L.expand_kv(kc, g)) / math.sqrt(acfg.head_dim),
@@ -577,7 +590,8 @@ def decode_step(params, cfg: ModelConfig, tokens: jax.Array, cache: dict, *,
     if "step" in cache:
         new_cache["step"] = step + 1
     h = _norm(cfg, params["final_norm"], h)
-    logits = lm_logits(params, cfg, h, policy)
+    with jax.named_scope("unembed_loss"):
+        logits = lm_logits(params, cfg, h, policy)
     return logits, new_cache
 
 

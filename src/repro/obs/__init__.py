@@ -1,6 +1,16 @@
 """``repro.obs`` — the opt-in flight-recorder/observability layer.
 
-Four small pieces, none of which touch simulation results:
+For the chip path (model and step programs), two modules that the
+package does not import itself:
+
+- :mod:`repro.obs.scopes` — the model's layer names (``LAYERS``, each a
+  ``jax.named_scope`` in the program) and ``op_layers``, which maps a
+  compiled program's instructions to them.
+- :mod:`repro.obs.runtime` — the process's compile spans and data-layer
+  counters, on the profiler's realtime clock.
+
+For the simulator, four small pieces, none of which touch simulation
+results:
 
 - :mod:`repro.obs.recorder` — :class:`SpanRecorder`: typed spans (op
   execution, port service, hidden vs stalling refresh pulses, off-chip

@@ -40,12 +40,13 @@ TID_PORT = 0
 TID_REFRESH = 1
 TID_REFRESH_STALL = 2
 
-_SPAN_TID = {"op": 0, "spill": 1,
+_SPAN_TID = {"op": 0, "spill": 1, "compile": 2,
              "port": TID_PORT, "refresh": TID_REFRESH,
              "refresh_stall": TID_REFRESH_STALL}
 _TRACK_NAMES = {
     (PID_ARRAY, 0): "ops",
     (PID_ARRAY, 1): "off-chip spills",
+    (PID_ARRAY, 2): "compiles",
     TID_PORT: "port",
     TID_REFRESH: "refresh (hidden)",
     TID_REFRESH_STALL: "refresh (stall)",

@@ -29,6 +29,10 @@ Span kinds (:data:`SPAN_KINDS`):
     An off-chip transfer for a spilled tensor (zero-width: the replay
     charges energy, off-chip *time* is priced globally against
     ``SystemConfig.offchip_bw_bps``).
+``compile``
+    One of JAX's compile events in this process (``repro.obs.runtime``):
+    named ``trace``, ``lower`` or ``backend``, with the program's
+    ``fun_name`` in args, stamped in realtime nanoseconds.
 
 Counter series (:meth:`SpanRecorder.counter`) sample per-bank occupancy
 in words at every allocate/free, cumulative traffic energy at each
@@ -44,10 +48,12 @@ JSON for Perfetto.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
-from typing import Iterator
+from typing import Iterator, Optional
 
-SPAN_KINDS = ("op", "port", "refresh", "refresh_stall", "spill")
+SPAN_KINDS = ("op", "port", "refresh", "refresh_stall", "spill",
+              "compile")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,11 +81,17 @@ class CounterSample:
 
 
 class SpanRecorder:
-    """Append-only sink for spans, counter samples, and run metadata."""
+    """Append-only sink for spans, counter samples, and run metadata.
 
-    def __init__(self) -> None:
-        self.spans: list[Span] = []
-        self.counters: list[CounterSample] = []
+    With ``maxlen`` it keeps only the newest ``maxlen`` spans and the
+    newest ``maxlen`` counter samples, for a recorder that lives as long
+    as its process."""
+
+    def __init__(self, maxlen: Optional[int] = None) -> None:
+        self.spans = [] if maxlen is None else collections.deque(
+            maxlen=maxlen)
+        self.counters = [] if maxlen is None else collections.deque(
+            maxlen=maxlen)
         self.meta: dict = {}
 
     def __len__(self) -> int:

@@ -1,0 +1,68 @@
+"""Counters of the chip path, held in this process's memory.
+
+Two :class:`~repro.obs.recorder.SpanRecorder` instances, one per process:
+
+- :data:`compiles`: a span of kind ``compile`` for each of JAX's compile
+  events (tracing to a jaxpr, lowering, and the backend compile, which also
+  covers a load from the persistent compilation cache), named by
+  :data:`COMPILE_EVENTS` with the program's ``fun_name`` in its args.
+  :func:`record_compiles` installs the listener; importing
+  ``repro.train.train_step`` or ``repro.train.serve_step`` calls it.  It
+  fires only when JAX compiles, so a steady step loop pays nothing.
+- :data:`data`: the data layer's counters, the newest :data:`MAXLEN`
+  samples: ``prefetch_wait_s`` for each ``Prefetcher.next`` call (the
+  seconds it blocked on its queue) and ``prefetch_produce_s`` for each
+  batch its producer made (the seconds that took).
+
+Stamps (span ``t0``/``t1``, sample ``t``) are realtime nanoseconds, the
+profiler's clock: a trace's event times are nanoseconds after its
+``profile_start_time``, so a compile or a data wait can be placed against
+a device trace's idle gaps.
+"""
+from __future__ import annotations
+
+import threading
+
+from repro.obs.recorder import SpanRecorder
+
+MAXLEN = 4096
+
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+compiles = SpanRecorder()
+data = SpanRecorder(maxlen=MAXLEN)
+
+_installed = False
+_lock = threading.Lock()
+
+
+def _on_event(event: str, start_time: float, end_time: float,
+              **kwargs) -> None:
+    name = COMPILE_EVENTS.get(event)
+    if name is not None:
+        compiles.span("compile", name, int(start_time * 1e9),
+                      int(end_time * 1e9),
+                      fun_name=str(kwargs.get("fun_name", "")))
+
+
+def record_compiles() -> None:
+    """Record JAX's compile events into :data:`compiles` from now on; a
+    second call does nothing."""
+    global _installed
+    with _lock:
+        if not _installed:
+            import jax
+            jax.monitoring.register_event_time_span_listener(_on_event)
+            _installed = True
+
+
+def backend_compiles(fun_name: str) -> int:
+    """Backend compiles (or persistent-cache loads) of the jitted function
+    ``fun_name`` recorded in this process."""
+    want = f"jit({fun_name})"
+    return sum(1 for s in compiles.spans_of("compile")
+               if s.name == "backend" and s.args.get("fun_name") == want)

@@ -66,11 +66,12 @@ def run(loop_cfg: LoopConfig, data_cfg: DataConfig, train_step: Callable,
     t_loop = time.time()
     try:
         for step in range(start_step, loop_cfg.total_steps):
-            batch = prefetch.next()
-            t0 = time.time()
-            state, metrics = train_step(state, batch)
-            jax.block_until_ready(metrics["loss"])
-            dt = time.time() - t0
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                batch = prefetch.next()
+                t0 = time.time()
+                state, metrics = train_step(state, batch)
+                jax.block_until_ready(metrics["loss"])
+                dt = time.time() - t0
 
             if loop_cfg.step_deadline_s and dt > loop_cfg.step_deadline_s:
                 strikes += 1

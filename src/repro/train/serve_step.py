@@ -10,6 +10,9 @@ import jax.numpy as jnp
 
 from repro.configs.common import ModelConfig
 from repro.models import layers as L
+from repro.obs import runtime
+
+runtime.record_compiles()
 
 
 def make_prefill_step(entry, cfg: ModelConfig, *, max_len: int,

@@ -21,10 +21,13 @@ import jax.numpy as jnp
 from repro.configs.common import ModelConfig
 from repro.core import duplex as dx
 from repro.models import layers as L
+from repro.obs import runtime
 from repro.optim import (AdamWConfig, OptConfig, SGDConfig, opt_init,
                          opt_update)
 from repro.train.losses import lm_cross_entropy
 from repro.utils import cast_tree
+
+runtime.record_compiles()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,10 +100,11 @@ def make_train_step(entry, cfg: ModelConfig, tcfg: TrainConfig,
             corr = dx.duplex_apply(branch, tcfg.duplex, out["emb"], taps,
                                    policy=policy, taps_pooled=True)
             hidden = jax.lax.stop_gradient(out["hidden"]) + corr
-            logits = module.lm_logits(backbone, cfg, hidden, policy)
-            loss, metrics = lm_cross_entropy(logits, batch["labels"],
-                                             batch.get("mask"),
-                                             z_loss=tcfg.z_loss)
+            with jax.named_scope("unembed_loss"):
+                logits = module.lm_logits(backbone, cfg, hidden, policy)
+                loss, metrics = lm_cross_entropy(logits, batch["labels"],
+                                                 batch.get("mask"),
+                                                 z_loss=tcfg.z_loss)
             return loss, metrics
 
         trainable = "branch"
@@ -110,10 +114,12 @@ def make_train_step(entry, cfg: ModelConfig, tcfg: TrainConfig,
             kw = {} if fe is None else {"frontend": fe}
             out = module.forward(backbone, cfg, batch["tokens"],
                                  policy=policy, **kw)
-            logits = module.lm_logits(backbone, cfg, out["hidden"], policy)
-            loss, metrics = lm_cross_entropy(logits, batch["labels"],
-                                             batch.get("mask"),
-                                             z_loss=tcfg.z_loss)
+            with jax.named_scope("unembed_loss"):
+                logits = module.lm_logits(backbone, cfg, out["hidden"],
+                                          policy)
+                loss, metrics = lm_cross_entropy(logits, batch["labels"],
+                                                 batch.get("mask"),
+                                                 z_loss=tcfg.z_loss)
             loss = loss + tcfg.aux_weight * out["aux"]
             return loss, metrics
 
@@ -144,8 +150,9 @@ def make_train_step(entry, cfg: ModelConfig, tcfg: TrainConfig,
             (loss, metrics), grads = grad_fn(state[trainable], frozen, batch)
 
         lr = _lr(tcfg, state["step"])
-        new_p, new_opt, om = opt_update(tcfg.opt, grads, state["opt"],
-                                        state[trainable], lr)
+        with jax.named_scope("optimizer"):
+            new_p, new_opt, om = opt_update(tcfg.opt, grads, state["opt"],
+                                            state[trainable], lr)
         new_state = dict(state)
         new_state[trainable] = new_p
         new_state["opt"] = new_opt

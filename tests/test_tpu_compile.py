@@ -7,6 +7,8 @@ HBM), so the kernels and one whole step are compiled here at real widths.
 The topology is described inside a fixture, never at import: describing it
 loads the TPU library, which one process at a time may hold.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +21,8 @@ from repro.kernels.bfp_quant import bfp_matmul_packed, bfp_quantize_pallas
 from repro.kernels.flash_attention import flash_attention
 from repro.launch.cells import activation_rules, build_cell
 from repro.launch.mesh import make_host_mesh
+from repro.obs.scopes import OTHER, op_layers, scope_layer
+from test_scopes import EXPECTED, step_programs
 
 HBM_BYTES = 16 * 2**30          # one TPU v5e chip
 
@@ -93,3 +97,17 @@ def test_granite_decode_step_fits_one_chip(topo):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < HBM_BYTES, used
+
+
+@pytest.mark.parametrize("arch,program", sorted(EXPECTED))
+def test_layer_scopes_survive_the_chip_compiler(one_chip, arch, program):
+    fn, args = step_programs(arch)[program]
+    args = jax.tree_util.tree_map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                                  args)
+    text = fn.lower(*args).compile().as_text()
+    named = {scope_layer(m)[0] for m in re.findall(r'op_name="([^"]*)"', text)}
+    assert set(EXPECTED[arch, program]) <= named
+    layers = op_layers(text)
+    heavy = re.findall(r"^\s*(?:ROOT )?%(\S+) = [^\n]*? (?:dot|convolution)\(",
+                       text, flags=re.M)
+    assert heavy and all(layers[n][0] != OTHER for n in heavy)
