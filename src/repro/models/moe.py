@@ -1,10 +1,28 @@
 """GShard-style Mixture-of-Experts layer (dropped tokens, capacity factor).
 
-Expert-parallel by construction: the dispatch/combine einsums carry an
-explicit expert axis that the sharding rules place on the ``model`` mesh axis
-(EP), so GSPMD materializes the all-to-all exchange between the token-sharded
-and expert-sharded layouts.  Tokens are processed in fixed-size groups so the
-dispatch tensors stay bounded: ``[G, g, E, C]`` with ``C ≈ g·k/E·cf``.
+Tokens are routed in fixed-size groups of ``g``.  Within a group the router
+takes ``top_k`` rounds of argmax over its float32 softmax gates; a round
+gives its choices the next free slots of their experts in token order,
+after the slots of the earlier rounds, and drops a choice past its
+expert's capacity ``C ≈ g·k/E·cf``; the kept gates are renormalised to sum
+to one.  Two routes move the tokens by that one rule:
+
+- **index** (``g >= _INDEX_ROUTE_MIN_GROUP``: training and prefill): the
+  router scatters int32 row ids into ``owner [E, G, C]``, dispatch
+  gathers each slot's row from the group and combine gathers each token's
+  ``top_k`` expert outputs back and weights them.  Its work grows as
+  ``g·k·D``.
+- **one-hot** (smaller groups: decode, where one group holds a batch's
+  tokens): dense ``[G, g, E, C]`` dispatch and combine tensors, contracted
+  by einsum.  Its work grows as ``g²·k·D``, but at a few tokens one small
+  contraction costs less than the gathers.
+
+The route is chosen from the group's static size, and each lowering is
+recorded in ``repro.obs.runtime.moe_routes``.  Both routes hand the
+experts ``[E, G, C, D]``: expert-parallel by construction, the sharding
+rules place that expert axis on the ``model`` mesh axis (EP), so GSPMD
+materializes the all-to-all exchange between the token-sharded and
+expert-sharded layouts.
 """
 from __future__ import annotations
 
@@ -15,7 +33,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
+from repro.obs import runtime
 from repro.utils import ceil_to, split_keys
+
+# Tokens per group from which moe_apply routes by index.  Set from both
+# routes timed on one TPU v5e at granite-moe widths (E 32, top 8, D 1024,
+# d_ff 512; tools/moe_routes.py): the one-hot route was faster up to 256
+# tokens (160 against 169 us a layer at 16, 215 against 251 at 256) and the
+# index route from 384 (252 against 263 us; 718 against 980 at 1,024).  A
+# row gather pays a fixed cost that the contraction of a few tokens does
+# not, while the contraction's cost grows with the square of the group.
+_INDEX_ROUTE_MIN_GROUP = 384
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +83,6 @@ def moe_apply(params, x: jax.Array, cfg: MoEConfig, *,
               policy: L.Policy = L.Policy(), bfp: L.BFPPolicy = L.NO_BFP):
     """x: [B,S,D] → (y [B,S,D], aux_loss scalar)."""
     b, s, d = x.shape
-    cd = policy.compute_dtype
     t = b * s
     g = min(cfg.group_size, t)
     tp = ceil_to(t, g)
@@ -63,19 +90,54 @@ def moe_apply(params, x: jax.Array, cfg: MoEConfig, *,
     if tp != t:
         xt = jnp.pad(xt, ((0, tp - t), (0, 0)))
     xg = xt.reshape(tp // g, g, d)                     # [G,g,D]
-    n_groups = tp // g
 
+    route = _index_route if g >= _INDEX_ROUTE_MIN_GROUP else _onehot_route
+    runtime.record_moe_route("index" if route is _index_route else "onehot",
+                             g, cfg.n_experts, capacity(cfg, g))
+    y, aux = route(params, xg, cfg, policy=policy, bfp=bfp)
+    with jax.named_scope("moe_combine"):
+        y = y.reshape(tp, d)[:t].reshape(b, s, d)
+    if "shared" in params:
+        with jax.named_scope("mlp"):
+            y = y + L.mlp(params["shared"], x, policy=policy, bfp=bfp)
+    return y.astype(x.dtype), aux
+
+
+def _gates(params, xg, cfg: MoEConfig, policy: L.Policy):
+    """Router gates [G,g,E] (f32 softmax) and the load-balancing aux loss
+    (Switch/GShard): E · Σ_e f_e · P_e."""
+    logits = L.dense(params["router"], xg, policy=policy).astype(
+        jnp.float32)
+    gates = jax.nn.softmax(logits, axis=-1)            # [G,g,E]
+    density = jnp.mean(gates, axis=1)                  # [G,E] mean router prob
+    top1 = jax.nn.one_hot(jnp.argmax(gates, -1), cfg.n_experts)
+    frac = jnp.mean(top1, axis=1)                      # [G,E] token fraction
+    aux = cfg.n_experts * jnp.mean(jnp.sum(density * frac, axis=-1))
+    return gates, aux
+
+
+def _experts(params, xe, cd, bfp: L.BFPPolicy):
+    """xe [E,G,C,D] → every expert's output on its slots, [E,G,C,D]."""
+    with jax.named_scope("moe_experts"):
+        wi = bfp.q(params["wi"]).astype(cd)
+        wo = bfp.q(params["wo"]).astype(cd)
+        h = jnp.einsum("egcd,edf->egcf", xe, wi)
+        if "wg" in params:
+            wg = bfp.q(params["wg"]).astype(cd)
+            h = jax.nn.silu(jnp.einsum("egcd,edf->egcf", xe, wg)) * h
+        else:
+            h = jax.nn.silu(h)
+        return jnp.einsum("egcf,efd->egcd", h, wo)
+
+
+def _onehot_route(params, xg, cfg: MoEConfig, *, policy: L.Policy,
+                  bfp: L.BFPPolicy):
+    """xg [G,g,D] → (y [G,g,D], aux): tokens moved by dense [G,g,E,C]
+    dispatch and combine tensors."""
+    n_groups, g, _ = xg.shape
+    cd = policy.compute_dtype
     with jax.named_scope("moe_router"):
-        logits = L.dense(params["router"], xg, policy=policy).astype(
-            jnp.float32)
-        gates = jax.nn.softmax(logits, axis=-1)        # [G,g,E]
-
-        # load-balancing aux loss (Switch/GShard): E · Σ_e f_e · P_e
-        density = jnp.mean(gates, axis=1)              # [G,E] mean router prob
-        top1 = jax.nn.one_hot(jnp.argmax(gates, -1), cfg.n_experts)
-        frac = jnp.mean(top1, axis=1)                  # [G,E] token fraction
-        aux = cfg.n_experts * jnp.mean(jnp.sum(density * frac, axis=-1))
-
+        gates, aux = _gates(params, xg, cfg, policy)
         cap = capacity(cfg, g)
         remaining = gates
         counts = jnp.zeros((n_groups, 1, cfg.n_experts), jnp.float32)
@@ -102,20 +164,59 @@ def moe_apply(params, x: jax.Array, cfg: MoEConfig, *,
 
     with jax.named_scope("moe_dispatch"):
         xe = jnp.einsum("gsec,gsd->egcd", dispatch, xg.astype(cd))  # [E,G,C,D]
-    with jax.named_scope("moe_experts"):
-        wi = bfp.q(params["wi"]).astype(cd)
-        wo = bfp.q(params["wo"]).astype(cd)
-        h = jnp.einsum("egcd,edf->egcf", xe, wi)
-        if "wg" in params:
-            wg = bfp.q(params["wg"]).astype(cd)
-            h = jax.nn.silu(jnp.einsum("egcd,edf->egcf", xe, wg)) * h
-        else:
-            h = jax.nn.silu(h)
-        ye = jnp.einsum("egcf,efd->egcd", h, wo)        # [E,G,C,D]
+    ye = _experts(params, xe, cd, bfp)
     with jax.named_scope("moe_combine"):
         y = jnp.einsum("gsec,egcd->gsd", combine, ye)   # [G,g,D]
-        y = y.reshape(tp, d)[:t].reshape(b, s, d)
-    if "shared" in params:
-        with jax.named_scope("mlp"):
-            y = y + L.mlp(params["shared"], x, policy=policy, bfp=bfp)
-    return y.astype(x.dtype), aux
+    return y, aux
+
+
+def _index_route(params, xg, cfg: MoEConfig, *, policy: L.Policy,
+                 bfp: L.BFPPolicy):
+    """xg [G,g,D] → (y [G,g,D], aux): tokens moved by int32 indices, the
+    D-wide rows gathered and never scattered."""
+    n_groups, g, d = xg.shape
+    e, cd = cfg.n_experts, policy.compute_dtype
+    with jax.named_scope("moe_router"):
+        gates, aux = _gates(params, xg, cfg, policy)
+        cap = capacity(cfg, g)
+        remaining = gates
+        counts = jnp.zeros((n_groups, 1, e), jnp.float32)
+        idx, slot, gate = [], [], []
+        for _ in range(cfg.top_k):
+            i = jnp.argmax(remaining, axis=-1)         # [G,g]
+            onehot = jax.nn.one_hot(i, e, dtype=jnp.float32)
+            pos = jnp.cumsum(onehot, axis=1) - 1.0 + counts  # [G,g,E]
+            counts = counts + jnp.sum(onehot, axis=1, keepdims=True)
+            idx.append(i)
+            slot.append(jnp.sum(pos * onehot, axis=-1))
+            gate.append(jnp.max(remaining, axis=-1))
+            remaining = remaining * (1.0 - onehot)
+        idx = jnp.stack(idx, -1)                       # [G,g,k]
+        slot = jnp.stack(slot, -1).astype(jnp.int32)
+        keep = slot < cap
+        gate = jnp.where(keep, jnp.stack(gate, -1), 0.0)
+        gate = gate / jnp.maximum(jnp.sum(gate, -1, keepdims=True), 1e-9)
+
+        # owner: the row of xg [G*g, D] that fills each slot; an empty
+        # slot's is G*g, past the end, which the gather reads as zeros.  A
+        # dropped choice writes to the spare slot ``cap``.
+        grp = jnp.arange(n_groups, dtype=jnp.int32)[:, None, None]
+        tok = grp * g + jnp.arange(g, dtype=jnp.int32)[:, None]
+        owner = jnp.full((e, n_groups, cap + 1), n_groups * g, jnp.int32)
+        owner = owner.at[idx, grp, jnp.where(keep, slot, cap)].set(
+            jnp.broadcast_to(tok, idx.shape))[:, :, :cap]
+        # each choice's row of ye [E*G*C, D]; a dropped one weighs 0
+        row = (idx * n_groups + grp) * cap + jnp.minimum(slot, cap - 1)
+
+    # Flat rows, not rows within each group: one chip runs the flat gather
+    # 3.9 times faster at 8 x 4096, though GSPMD cannot keep it local to a
+    # device that holds some of the groups.
+    with jax.named_scope("moe_dispatch"):
+        xe = xg.astype(cd).reshape(-1, d).at[owner].get(
+            mode="fill", fill_value=0)                 # [E,G,C,D]
+    ye = _experts(params, xe, cd, bfp)
+    with jax.named_scope("moe_combine"):
+        ye = ye.reshape(-1, d)
+        y = sum(gate[..., j, None] * ye[row[..., j]].astype(jnp.float32)
+                for j in range(cfg.top_k))             # [G,g,D]
+    return y.astype(cd), aux

@@ -40,13 +40,14 @@ TID_PORT = 0
 TID_REFRESH = 1
 TID_REFRESH_STALL = 2
 
-_SPAN_TID = {"op": 0, "spill": 1, "compile": 2,
+_SPAN_TID = {"op": 0, "spill": 1, "compile": 2, "lowering": 3,
              "port": TID_PORT, "refresh": TID_REFRESH,
              "refresh_stall": TID_REFRESH_STALL}
 _TRACK_NAMES = {
     (PID_ARRAY, 0): "ops",
     (PID_ARRAY, 1): "off-chip spills",
     (PID_ARRAY, 2): "compiles",
+    (PID_ARRAY, 3): "lowerings",
     TID_PORT: "port",
     TID_REFRESH: "refresh (hidden)",
     TID_REFRESH_STALL: "refresh (stall)",
@@ -75,7 +76,7 @@ def chrome_trace_events(recorder: SpanRecorder) -> list[dict]:
         args = {**s.args, "t0_s": s.t0, "t1_s": s.t1}
         if s.bank >= 0:
             args["bank"] = s.bank
-        if s.kind == "spill":                  # zero-width: instant event
+        if s.kind in ("spill", "lowering"):    # zero-width: instant event
             events.append({"ph": "i", "s": "t", "pid": pid, "tid": tid,
                            "ts": _us(s.t0), "name": s.name,
                            "cat": s.kind, "args": args})

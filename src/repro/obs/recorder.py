@@ -33,6 +33,10 @@ Span kinds (:data:`SPAN_KINDS`):
     One of JAX's compile events in this process (``repro.obs.runtime``):
     named ``trace``, ``lower`` or ``backend``, with the program's
     ``fun_name`` in args, stamped in realtime nanoseconds.
+``lowering``
+    Zero-width: one layer's lowering choice, made while a program is
+    traced (``repro.obs.runtime.moe_routes``): named by the route, with
+    its static sizes in args.
 
 Counter series (:meth:`SpanRecorder.counter`) sample per-bank occupancy
 in words at every allocate/free, cumulative traffic energy at each
@@ -53,7 +57,7 @@ import dataclasses
 from typing import Iterator, Optional
 
 SPAN_KINDS = ("op", "port", "refresh", "refresh_stall", "spill",
-              "compile")
+              "compile", "lowering")
 
 
 @dataclasses.dataclass(frozen=True)

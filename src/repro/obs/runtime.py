@@ -1,6 +1,6 @@
 """Counters of the chip path, held in this process's memory.
 
-Two :class:`~repro.obs.recorder.SpanRecorder` instances, one per process:
+Three :class:`~repro.obs.recorder.SpanRecorder` instances, one per process:
 
 - :data:`compiles`: a span of kind ``compile`` for each of JAX's compile
   events (tracing to a jaxpr, lowering, and the backend compile, which also
@@ -13,6 +13,12 @@ Two :class:`~repro.obs.recorder.SpanRecorder` instances, one per process:
   samples: ``prefetch_wait_s`` for each ``Prefetcher.next`` call (the
   seconds it blocked on its queue) and ``prefetch_produce_s`` for each
   batch its producer made (the seconds that took).
+- :data:`moe_routes`: the newest :data:`MAXLEN` MoE lowerings, each a
+  zero-width span of kind ``lowering`` that ``repro.models.moe`` records
+  while a program is traced: named by the route (``index`` or
+  ``onehot``), with the group size, experts and capacity in its args.  A
+  program runs the route it was lowered with, so :func:`moe_routes_of`
+  says which route each step program took.
 
 Stamps (span ``t0``/``t1``, sample ``t``) are realtime nanoseconds, the
 profiler's clock: a trace's event times are nanoseconds after its
@@ -22,6 +28,7 @@ a device trace's idle gaps.
 from __future__ import annotations
 
 import threading
+import time
 
 from repro.obs.recorder import SpanRecorder
 
@@ -35,6 +42,7 @@ COMPILE_EVENTS = {
 
 compiles = SpanRecorder()
 data = SpanRecorder(maxlen=MAXLEN)
+moe_routes = SpanRecorder(maxlen=MAXLEN)
 
 _installed = False
 _lock = threading.Lock()
@@ -66,3 +74,20 @@ def backend_compiles(fun_name: str) -> int:
     want = f"jit({fun_name})"
     return sum(1 for s in compiles.spans_of("compile")
                if s.name == "backend" and s.args.get("fun_name") == want)
+
+
+def record_moe_route(route: str, group: int, experts: int,
+                     capacity: int) -> None:
+    """Record one MoE layer's lowering; called while a program is traced."""
+    t = int(time.time() * 1e9)        # the clock of JAX's compile events
+    moe_routes.span("lowering", route, t, t, group=group, experts=experts,
+                    capacity=capacity)
+
+
+def moe_routes_of(fun_name: str) -> list:
+    """The MoE lowerings recorded while the jitted function ``fun_name``
+    was traced, in order (its ``trace`` compile spans hold them)."""
+    traces = [(s.t0, s.t1) for s in compiles.spans_of("compile")
+              if s.name == "trace" and s.args.get("fun_name") == fun_name]
+    return [r for r in moe_routes.spans
+            if any(a <= r.t0 <= b for a, b in traces)]

@@ -1,5 +1,7 @@
 """Layer scopes in the step programs, ``op_layers``, and the runtime
 counters of ``repro.obs`` (compiles, data waits) on the profiler's clock."""
+import collections
+import dataclasses
 import glob
 import os
 import re
@@ -12,7 +14,7 @@ from jax.profiler import ProfileData, TraceAnnotation
 
 from repro.data.pipeline import Prefetcher
 from repro.launch.cells import duplex_tcfg
-from repro.models import layers as L, registry
+from repro.models import layers as L, moe, registry
 from repro.obs import runtime
 from repro.obs.export import chrome_trace_events
 from repro.obs.recorder import SpanRecorder
@@ -35,17 +37,18 @@ EXPECTED = {
 MIXERS = {"mamba2-780m": "ssd", "recurrentgemma-9b": "lru"}
 
 
-def step_programs(arch: str) -> dict:
+def step_programs(arch: str, s: int = 32, **overrides) -> dict:
     """{program: (jitted, args)} of a smoke preset, as the launchers
-    build them, for shapes only."""
+    build them, for shapes only: batch 2, ``s`` tokens a sequence, the
+    preset's config with ``overrides``."""
     entry = registry.get(arch)
-    cfg = entry.config("smoke")
+    cfg = dataclasses.replace(entry.config("smoke"), **overrides)
     policy = L.Policy(compute_dtype=jnp.bfloat16)
     tcfg = duplex_tcfg(cfg)
     state = jax.eval_shape(
         lambda k: ts.init_state(k, entry, cfg, tcfg, policy),
         jax.random.PRNGKey(0))
-    b, s = 2, 32
+    b = 2
     toks = jax.ShapeDtypeStruct((b, s), jnp.int32)
     prefill = jax.jit(ss.make_prefill_step(entry, cfg, max_len=s + 8,
                                            policy=policy, logits_mode="last"))
@@ -106,6 +109,62 @@ def test_recurrent_mixer_scope_in_forward(arch):
     text = jax.jit(lambda p, t: entry.module.forward(p, cfg, t)["hidden"]) \
         .lower(params, toks).compile().as_text()
     assert MIXERS[arch] in {lp[0] for lp in op_layers(text).values()}
+
+
+MOVES = re.compile(r"^\s*(?:ROOT )?%(\S+) = [^\n]*? (?:gather|scatter)\(",
+                   re.M)
+ROUTING = {"moe_router", "moe_dispatch", "moe_combine"}
+_ROUTED: dict = {}
+
+
+def routed(index_from: int) -> dict:
+    """{program: (MoE routes recorded, compiled text)} of the smoke
+    granite-moe preset in groups of ``moe._INDEX_ROUTE_MIN_GROUP`` tokens
+    (train and prefill fill one group, decode's holds its batch of 2), with
+    the index route taken from ``index_from`` tokens a group."""
+    if index_from not in _ROUTED:
+        n = moe._INDEX_ROUTE_MIN_GROUP
+        out = {}
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(moe, "_INDEX_ROUTE_MIN_GROUP", index_from)
+            t0 = time.time_ns()
+            progs = step_programs("granite-moe-1b-a400m", s=n // 2,
+                                  moe_group_size=n)
+            for name, (fn, args) in progs.items():
+                text = fn.lower(*args).compile().as_text()
+                routes = [(r.name, r.args["group"])
+                          for r in runtime.moe_routes_of(f"{name}_step")
+                          if r.t0 >= t0]
+                out[name] = routes, text
+        _ROUTED[index_from] = out
+    return _ROUTED[index_from]
+
+
+@pytest.mark.parametrize("program,want", [
+    ("train", "index"), ("prefill", "index"), ("decode", "onehot")])
+def test_moe_route_by_group_size(program, want):
+    n = moe._INDEX_ROUTE_MIN_GROUP
+    routes, _ = routed(n)[program]
+    assert routes and set(routes) == {(want, 2 if program == "decode" else n)}
+
+
+def test_decode_moves_no_tokens_by_gather():
+    _, text = routed(moe._INDEX_ROUTE_MIN_GROUP)["decode"]
+    layers = op_layers(text)
+    assert not [n for n in MOVES.findall(text)
+                if layers[n][0] in ("moe_dispatch", "moe_combine")]
+
+
+@pytest.mark.parametrize("program", ["train", "prefill"])
+def test_index_route_gathers_map_to_routing_layers(program):
+    counts = {}
+    for index_from in (moe._INDEX_ROUTE_MIN_GROUP, 2**30):
+        _, text = routed(index_from)[program]
+        layers = op_layers(text)
+        counts[index_from] = collections.Counter(
+            layers[n][0] for n in MOVES.findall(text))
+    new = counts[moe._INDEX_ROUTE_MIN_GROUP] - counts[2**30]
+    assert new and set(new) <= ROUTING, new
 
 
 # a module by hand: a layer loop whose weight cast the compiler hoisted and

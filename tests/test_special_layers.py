@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.models import hybrid, layers as L, moe, ssm
+from repro.obs import runtime
 
 P32 = L.Policy(compute_dtype=jnp.float32)
 
@@ -193,3 +194,74 @@ def test_moe_gradients_flow_to_router_and_experts():
                  )(params)
     assert float(jnp.max(jnp.abs(g["router"]["w"]))) > 0
     assert float(jnp.max(jnp.abs(g["wi"]))) > 0
+
+
+# the same tokens through both routes: (experts, top_k, capacity factor,
+# batch, seq, group, shared expert)
+ROUTE_CASES = {
+    "no-drops": (4, 2, 100.0, 2, 16, 16, False),
+    "heavy-drops": (4, 2, 0.25, 2, 16, 16, False),
+    "padded": (4, 2, 1.25, 3, 10, 16, False),       # 30 tokens, groups of 16
+    "groups": (8, 2, 1.25, 4, 32, 16, False),
+    "top1": (8, 1, 1.25, 2, 32, 32, False),
+    "top8": (8, 8, 1.25, 2, 32, 32, False),
+    "shared": (4, 1, 1.25, 2, 16, 16, True),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(ROUTE_CASES))
+def test_moe_index_route_matches_onehot_route(case, dtype):
+    e, k, cf, b, s, g, shared = ROUTE_CASES[case]
+    cfg = moe.MoEConfig(d_model=8, d_ff=16, n_experts=e, top_k=k,
+                        capacity_factor=cf, group_size=g,
+                        shared_expert=shared)
+    params = moe.moe_init(jax.random.PRNGKey(3), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(4), (b * s, 8))
+    x = jnp.pad(x, ((0, -(b * s) % g), (0, 0))).reshape(-1, g, 8)
+    policy = L.Policy(compute_dtype=dtype)
+
+    def run(route, params):
+        return route(params, x, cfg, policy=policy, bfp=L.NO_BFP)
+
+    (want, aux_o), (got, aux_i) = (jax.jit(run, static_argnums=0)(r, params)
+                                   for r in (moe._onehot_route,
+                                             moe._index_route))
+    assert got.dtype == want.dtype == dtype and float(aux_i) == float(aux_o)
+    want, got = np.asarray(want, np.float32), np.asarray(got, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+        def grads(route):
+            return jax.jit(jax.grad(
+                lambda p: jnp.sum(run(route, p)[0] ** 2)))(params)
+        for gi, go in zip(jax.tree_util.tree_leaves(grads(moe._index_route)),
+                          jax.tree_util.tree_leaves(grads(moe._onehot_route))):
+            np.testing.assert_allclose(gi, go, rtol=1e-5, atol=1e-5)
+    else:
+        # the one-hot route rounds each gate, their sum and each weight to
+        # bf16; the index route normalises in f32 and rounds each weight
+        eps = float(jnp.finfo(jnp.bfloat16).eps)
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=2 * eps * np.abs(want).max())
+
+
+def test_moe_apply_routes_by_group_size_and_records_it():
+    runtime.record_compiles()
+    n = moe._INDEX_ROUTE_MIN_GROUP
+    cfg = moe.MoEConfig(d_model=8, d_ff=16, n_experts=4, top_k=2,
+                        group_size=n, shared_expert=True)
+    params = moe.moe_init(jax.random.PRNGKey(5), cfg)
+
+    def moe_route_probe(x):
+        return moe.moe_apply(params, x, cfg, policy=P32)[0]
+
+    seen = []
+    for tokens in (n - 1, n, 4 * n):
+        jax.jit(moe_route_probe).lower(jnp.ones((1, tokens, 8)))
+        r = runtime.moe_routes_of("moe_route_probe")[-1]
+        seen.append((r.name, r.args["group"], r.args["experts"],
+                     r.args["capacity"]))
+    assert [s[:2] for s in seen] == [("onehot", n - 1), ("index", n),
+                                     ("index", n)]
+    assert all(s[2:] == (4, moe.capacity(cfg, s[1])) for s in seen)

@@ -8,6 +8,7 @@ The topology is described inside a fixture, never at import: describing it
 loads the TPU library, which one process at a time may hold.
 """
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from repro.kernels.bfp_quant import bfp_matmul_packed, bfp_quantize_pallas
 from repro.kernels.flash_attention import flash_attention
 from repro.launch.cells import activation_rules, build_cell
 from repro.launch.mesh import make_host_mesh
+from repro.obs import runtime
 from repro.obs.scopes import OTHER, op_layers, scope_layer
 from test_scopes import EXPECTED, step_programs
 
@@ -94,6 +96,23 @@ def test_granite_decode_step_fits_one_chip(topo):
     with mesh, ctx.activation_sharding(mesh, activation_rules(cfg, mesh)):
         compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                            donate_argnums=donate).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 0 < used < HBM_BYTES, used
+
+
+def test_granite_train_step_routes_by_index_and_fits_one_chip(topo):
+    mesh = make_host_mesh(devices=topo.devices[:1])
+    shape = ShapeSpec("train_8x4k", 4096, 8, "train")
+    t0 = time.time_ns()
+    fn, args, in_sh, out_sh, donate, cfg, _ = build_cell(
+        "granite-moe-1b-a400m", shape, mesh)
+    with mesh, ctx.activation_sharding(mesh, activation_rules(cfg, mesh)):
+        compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
+                           donate_argnums=donate).lower(*args).compile()
+    routes = {(r.name, r.args["group"])
+              for r in runtime.moe_routes_of("train_step") if r.t0 >= t0}
+    assert routes == {("index", 4096)}
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < HBM_BYTES, used
