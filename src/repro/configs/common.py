@@ -55,11 +55,17 @@ class ModelConfig:
     act: str = "silu"         # silu | gelu
 
     # moe
-    n_experts: int = 0
+    n_experts: int = 0        # experts this layer holds
     top_k: int = 0
     capacity_factor: float = 1.25
     moe_group_size: int = 1024
     shared_expert: bool = False
+    shared_d_ff: int = 0      # the shared expert's width; 0: d_ff
+    # expert parallelism: the router scores ``n_routed_experts`` (0: the
+    # experts held are all of them); this layer holds ``n_experts`` of
+    # them from router output ``expert_offset`` on
+    n_routed_experts: int = 0
+    expert_offset: int = 0
 
     # ssm (mamba2)
     ssm_state: int = 0
@@ -79,8 +85,15 @@ class ModelConfig:
 
     # norms / vocab
     norm: str = "rmsnorm"     # rmsnorm | layernorm
+    norm_eps: float = 1e-6    # rmsnorm's
     vocab_pad_multiple: int = 256
     embed_scale: bool = False  # gemma-style sqrt(d_model) embedding scale
+
+    # granite's multipliers; the defaults apply none
+    embed_mult: float = 1.0   # embeddings times this
+    attn_mult: Optional[float] = None  # attention scale; None: 1/sqrt(hd)
+    resid_mult: float = 1.0   # each sublayer's output times this, then added
+    logits_div: float = 1.0   # logits over this
 
     # attention families that are quadratic cannot serve 500k contexts
     supports_long_context: bool = False
@@ -102,6 +115,9 @@ class ModelConfig:
             assert self.n_heads % self.n_kv == 0, self.name
         if any(s.mlp == "moe" for s in self.pattern + self.remainder):
             assert self.n_experts and self.top_k, self.name
+            routed = self.n_routed_experts or self.n_experts
+            assert self.expert_offset + self.n_experts <= routed, self.name
+            assert self.top_k <= routed, self.name
         if "ssd" in kinds:
             assert self.ssm_state, self.name
         if "lru" in kinds:

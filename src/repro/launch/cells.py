@@ -31,7 +31,7 @@ def duplex_tcfg(cfg, backbone_dtype=jnp.bfloat16) -> ts.TrainConfig:
     to bf16 at use.
     """
     d_branch = max(256, cfg.d_model // 8)
-    n_blocks = max(2, min(8, cfg.n_rep))
+    n_blocks = max(2, min(8, ts.tapped_layers(cfg)))
     return ts.TrainConfig(
         mode="duplex",
         duplex=dx.DuplexConfig(

@@ -146,6 +146,11 @@ def _softcap(scores: jax.Array, cap: float | None) -> jax.Array:
     return cap * jnp.tanh(scores / cap) if cap is not None else scores
 
 
+def _scaled(scores: jax.Array, head_dim: int, scale: float | None):
+    """Scores times ``scale``; None: over sqrt(head_dim)."""
+    return scores / math.sqrt(head_dim) if scale is None else scores * scale
+
+
 def expand_kv(k: jax.Array, g: int) -> jax.Array:
     """GQA expansion [B,S,KV,hd] → [B,S,KV·g,hd].
 
@@ -172,7 +177,7 @@ def _gqa_out(w, v):
 
 
 def full_attention(q, k, v, *, causal: bool, softcap=None,
-                   window: int | None = None):
+                   window: int | None = None, scale: float | None = None):
     """Materialized-scores attention (short sequences).
 
     q: [B,Sq,H,hd]; k, v: [B,Skv,KV,hd] (expanded internally for GQA).
@@ -182,7 +187,7 @@ def full_attention(q, k, v, *, causal: bool, softcap=None,
     skv, nkv = k.shape[1], k.shape[2]
     k = expand_kv(k, h // nkv)
     v = expand_kv(v, h // nkv)
-    scores = _softcap(_gqa_scores(q, k) / math.sqrt(hd), softcap)
+    scores = _softcap(_scaled(_gqa_scores(q, k), hd, scale), softcap)
     qpos, kpos = jnp.arange(sq), jnp.arange(skv)
     mask = jnp.ones((sq, skv), bool)
     if causal:
@@ -197,7 +202,8 @@ def full_attention(q, k, v, *, causal: bool, softcap=None,
 def blockwise_attention(q, k, v, *, causal: bool, softcap=None,
                         window: int | None = None,
                         q_chunk: int = 512, kv_chunk: int = 1024,
-                        causal_skip: bool = False):
+                        causal_skip: bool = False,
+                        scale: float | None = None):
     """Flash-style online-softmax attention via lax.scan over chunks.
 
     Memory is O(Sq·kv_chunk) instead of O(Sq·Skv).
@@ -220,7 +226,8 @@ def blockwise_attention(q, k, v, *, causal: bool, softcap=None,
     kp = jnp.pad(k, ((0, 0), (0, skv_p - skv), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, skv_p - skv), (0, 0), (0, 0)))
     nq, nkv_chunks = sq_p // q_chunk, skv_p // kv_chunk
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     qs = qp.reshape(b, nq, q_chunk, h, hd).swapaxes(0, 1)
 
@@ -284,7 +291,7 @@ def blockwise_attention(q, k, v, *, causal: bool, softcap=None,
 
 
 def decode_attention(q, k_cache, v_cache, cur_len, *, softcap=None,
-                     window: int | None = None):
+                     window: int | None = None, scale: float | None = None):
     """Single-token decode over a [B,Smax,KV,hd] cache. q: [B,1,H,hd].
 
     The score constraint (§Perf H4) keeps the KV-cache's sequence sharding
@@ -297,7 +304,7 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, softcap=None,
     smax, nkv = k_cache.shape[1], k_cache.shape[2]
     kc = expand_kv(k_cache, h // nkv)
     vc = expand_kv(v_cache, h // nkv)
-    scores = _softcap(_gqa_scores(q, kc) / math.sqrt(hd), softcap)
+    scores = _softcap(_scaled(_gqa_scores(q, kc), hd, scale), softcap)
     scores = constrain(scores, "dec_scores")              # [B,H,1,Smax]
     kpos = jnp.arange(smax)
     mask = kpos < cur_len                                 # [Smax]
@@ -329,6 +336,7 @@ class AttnConfig:
     q_chunk: int = 512
     kv_chunk: int = 1024
     causal_skip: bool = False            # §Perf: skip fully-masked kv chunks
+    scale: float | None = None           # scores' scale; None: 1/sqrt(hd)
     # fused Pallas flash kernel (TPU runtime; interpret=True on CPU tests).
     # Scores/softmax state stay in VMEM — see EXPERIMENTS.md §Perf H3.
     use_flash: bool = False
@@ -377,7 +385,7 @@ def attention_layer(p, x, cfg: AttnConfig, *, policy=Policy(), bfp=NO_BFP,
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     q, k, v = _project_qkv(p, x, kv_x, cfg, policy, bfp, positions, kv_positions)
     causal = cfg.causal and self_attn
-    if cfg.use_flash and cfg.window is None:
+    if cfg.use_flash and cfg.window is None and cfg.scale is None:
         from repro.kernels.flash_attention import flash_attention
         qc = min(cfg.q_chunk, s)
         kc = min(cfg.kv_chunk, kv_x.shape[1])
@@ -390,10 +398,10 @@ def attention_layer(p, x, cfg: AttnConfig, *, policy=Policy(), bfp=NO_BFP,
         o = blockwise_attention(q, k, v, causal=causal, softcap=cfg.softcap,
                                 window=cfg.window, q_chunk=cfg.q_chunk,
                                 kv_chunk=cfg.kv_chunk,
-                                causal_skip=cfg.causal_skip)
+                                causal_skip=cfg.causal_skip, scale=cfg.scale)
     else:
         o = full_attention(q, k, v, causal=causal, softcap=cfg.softcap,
-                           window=cfg.window)
+                           window=cfg.window, scale=cfg.scale)
     o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
     return dense(p["wo"], o, policy=policy, bfp=bfp)
 
@@ -411,7 +419,7 @@ def attention_decode(p, x, cache: dict, cfg: AttnConfig, *, policy=Policy()):
         v_cache = lax.dynamic_update_slice_in_dim(
             cache["v"], v.astype(cache["v"].dtype), cur, axis=1)
     o = decode_attention(q, k_cache, v_cache, cur + 1, softcap=cfg.softcap,
-                         window=cfg.window)
+                         window=cfg.window, scale=cfg.scale)
     o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
     out = dense(p["wo"], o, policy=policy)
     new_cache = {"k": k_cache, "v": v_cache, "len": cur + 1}

@@ -18,7 +18,15 @@ to one.  Two routes move the tokens by that one rule:
   contraction costs less than the gathers.
 
 The route is chosen from the group's static size, and each lowering is
-recorded in ``repro.obs.runtime.moe_routes``.  Both routes hand the
+recorded in ``repro.obs.runtime.moe_routes``.
+
+Expert parallelism: a layer may hold ``n_experts`` of the router's
+``n_routed`` experts, from router output ``offset`` on, as one chip of an
+expert-parallel group does.  It routes, places and drops every choice over
+all the router's experts and normalises the gates over all kept choices,
+as the whole layer does, then dispatches and combines only the choices of
+the experts it holds: its output is their share of the whole layer's.  A
+shared expert is every token's, on every chip.  Both routes hand the
 experts ``[E, G, C, D]``: expert-parallel by construction, the sharding
 rules place that expert axis on the ``model`` mesh axis (EP), so GSPMD
 materializes the all-to-all exchange between the token-sharded and
@@ -56,6 +64,17 @@ class MoEConfig:
     group_size: int = 1024
     gated: bool = True
     shared_expert: bool = False   # llama4-style always-on expert
+    shared_d_ff: int = 0          # its width; 0: d_ff
+    n_routed: int = 0             # the router's experts; 0: n_experts
+    offset: int = 0               # router index of the first expert held
+
+    @property
+    def routed(self) -> int:
+        return self.n_routed or self.n_experts
+
+    @property
+    def holds_all(self) -> bool:
+        return self.routed == self.n_experts
 
 
 def moe_init(key, cfg: MoEConfig) -> dict:
@@ -63,19 +82,22 @@ def moe_init(key, cfg: MoEConfig) -> dict:
     e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
     scale = 1.0 / math.sqrt(d)
     p = {
-        "router": L.dense_init(ks["router"], d, e, scale=0.02),
+        "router": L.dense_init(ks["router"], d, cfg.routed, scale=0.02),
         "wi": jax.random.normal(ks["wi"], (e, d, f), jnp.float32) * scale,
         "wo": jax.random.normal(ks["wo"], (e, f, d), jnp.float32) / math.sqrt(f),
     }
     if cfg.gated:
         p["wg"] = jax.random.normal(ks["wg"], (e, d, f), jnp.float32) * scale
     if cfg.shared_expert:
-        p["shared"] = L.mlp_init(ks["shared"], d, f, gated=cfg.gated)
+        p["shared"] = L.mlp_init(ks["shared"], d, cfg.shared_d_ff or f,
+                                 gated=cfg.gated)
     return p
 
 
 def capacity(cfg: MoEConfig, group: int) -> int:
-    c = int(math.ceil(group * cfg.top_k / cfg.n_experts * cfg.capacity_factor))
+    """Slots of each expert in a group: of the router's experts, so that a
+    share of them keeps the choices the whole layer keeps."""
+    c = int(math.ceil(group * cfg.top_k / cfg.routed * cfg.capacity_factor))
     return max(4, ceil_to(c, 4))
 
 
@@ -93,7 +115,8 @@ def moe_apply(params, x: jax.Array, cfg: MoEConfig, *,
 
     route = _index_route if g >= _INDEX_ROUTE_MIN_GROUP else _onehot_route
     runtime.record_moe_route("index" if route is _index_route else "onehot",
-                             g, cfg.n_experts, capacity(cfg, g))
+                             g, cfg.n_experts, capacity(cfg, g),
+                             routed=cfg.routed, offset=cfg.offset)
     y, aux = route(params, xg, cfg, policy=policy, bfp=bfp)
     with jax.named_scope("moe_combine"):
         y = y.reshape(tp, d)[:t].reshape(b, s, d)
@@ -110,9 +133,9 @@ def _gates(params, xg, cfg: MoEConfig, policy: L.Policy):
         jnp.float32)
     gates = jax.nn.softmax(logits, axis=-1)            # [G,g,E]
     density = jnp.mean(gates, axis=1)                  # [G,E] mean router prob
-    top1 = jax.nn.one_hot(jnp.argmax(gates, -1), cfg.n_experts)
+    top1 = jax.nn.one_hot(jnp.argmax(gates, -1), cfg.routed)
     frac = jnp.mean(top1, axis=1)                      # [G,E] token fraction
-    aux = cfg.n_experts * jnp.mean(jnp.sum(density * frac, axis=-1))
+    aux = cfg.routed * jnp.mean(jnp.sum(density * frac, axis=-1))
     return gates, aux
 
 
@@ -136,21 +159,27 @@ def _onehot_route(params, xg, cfg: MoEConfig, *, policy: L.Policy,
     dispatch and combine tensors."""
     n_groups, g, _ = xg.shape
     cd = policy.compute_dtype
+    held = slice(cfg.offset, cfg.offset + cfg.n_experts)
     with jax.named_scope("moe_router"):
         gates, aux = _gates(params, xg, cfg, policy)
         cap = capacity(cfg, g)
         remaining = gates
-        counts = jnp.zeros((n_groups, 1, cfg.n_experts), jnp.float32)
+        counts = jnp.zeros((n_groups, 1, cfg.routed), jnp.float32)
         dispatch = jnp.zeros((n_groups, g, cfg.n_experts, cap), cd)
         combine = jnp.zeros((n_groups, g, cfg.n_experts, cap), cd)
+        if not cfg.holds_all:
+            kept = jnp.zeros((n_groups, g), jnp.float32)   # kept gates' sum
         for _ in range(cfg.top_k):
             idx = jnp.argmax(remaining, axis=-1)       # [G,g]
             gate_k = jnp.take_along_axis(remaining, idx[..., None], -1)[..., 0]
-            onehot = jax.nn.one_hot(idx, cfg.n_experts, dtype=jnp.float32)
+            onehot = jax.nn.one_hot(idx, cfg.routed, dtype=jnp.float32)
             # position of each token within its expert's capacity buffer
             pos = jnp.cumsum(onehot, axis=1) - 1.0 + counts  # [G,g,E]
             counts = counts + jnp.sum(onehot, axis=1, keepdims=True)
             keep = (pos < cap) & (onehot > 0)
+            if not cfg.holds_all:
+                kept = kept + jnp.where(jnp.any(keep, -1), gate_k, 0.0)
+                pos, keep = pos[..., held], keep[..., held]
             pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap,
                                     dtype=jnp.float32)
             d_k = (pos_oh * keep[..., None]).astype(cd)  # [G,g,E,C]
@@ -159,7 +188,10 @@ def _onehot_route(params, xg, cfg: MoEConfig, *, policy: L.Policy,
             remaining = remaining * (1.0 - onehot)
 
         # normalize the kept top-k gates to sum to 1 per token
-        denom = jnp.sum(combine, axis=(-2, -1), keepdims=True)
+        if cfg.holds_all:
+            denom = jnp.sum(combine, axis=(-2, -1), keepdims=True)
+        else:
+            denom = kept[..., None, None].astype(cd)
         combine = combine / jnp.maximum(denom, 1e-9)
 
     with jax.named_scope("moe_dispatch"):
@@ -180,11 +212,11 @@ def _index_route(params, xg, cfg: MoEConfig, *, policy: L.Policy,
         gates, aux = _gates(params, xg, cfg, policy)
         cap = capacity(cfg, g)
         remaining = gates
-        counts = jnp.zeros((n_groups, 1, e), jnp.float32)
+        counts = jnp.zeros((n_groups, 1, cfg.routed), jnp.float32)
         idx, slot, gate = [], [], []
         for _ in range(cfg.top_k):
             i = jnp.argmax(remaining, axis=-1)         # [G,g]
-            onehot = jax.nn.one_hot(i, e, dtype=jnp.float32)
+            onehot = jax.nn.one_hot(i, cfg.routed, dtype=jnp.float32)
             pos = jnp.cumsum(onehot, axis=1) - 1.0 + counts  # [G,g,E]
             counts = counts + jnp.sum(onehot, axis=1, keepdims=True)
             idx.append(i)
@@ -196,6 +228,12 @@ def _index_route(params, xg, cfg: MoEConfig, *, policy: L.Policy,
         keep = slot < cap
         gate = jnp.where(keep, jnp.stack(gate, -1), 0.0)
         gate = gate / jnp.maximum(jnp.sum(gate, -1, keepdims=True), 1e-9)
+        if not cfg.holds_all:
+            # the held experts' choices, numbered from the first held
+            idx = idx - cfg.offset
+            mine = (idx >= 0) & (idx < e)
+            keep, gate = keep & mine, jnp.where(mine, gate, 0.0)
+            idx = jnp.clip(idx, 0, e - 1)
 
         # owner: the row of xg [G*g, D] that fills each slot; an empty
         # slot's is G*g, past the end, which the gather reads as zeros.  A

@@ -11,10 +11,10 @@ from typing import Callable, Optional
 
 import jax.numpy as jnp
 
-from repro.configs import (gemma2_9b, granite_3_8b, granite_moe_1b,
-                           llama32_vision_90b, llama4_maverick, mamba2_780m,
-                           qwen2_72b, recurrentgemma_9b, starcoder2_7b,
-                           whisper_base)
+from repro.configs import (gemma2_9b, granite_3_8b, granite_4_h_small,
+                           granite_moe_1b, llama32_vision_90b, llama4_maverick,
+                           mamba2_780m, qwen2_72b, recurrentgemma_9b,
+                           starcoder2_7b, whisper_base)
 from repro.configs.common import ModelConfig, SHAPES, ShapeSpec
 from repro.models import encdec, transformer
 
@@ -50,6 +50,7 @@ _CONF = {
     "recurrentgemma-9b": (recurrentgemma_9b, transformer),
     "granite-moe-1b-a400m": (granite_moe_1b, transformer),
     "llama4-maverick-400b-a17b": (llama4_maverick, transformer),
+    "granite-4.0-h-small": (granite_4_h_small, transformer),
 }
 
 ARCHS: dict[str, ArchEntry] = {
@@ -65,7 +66,7 @@ def get(name: str) -> ArchEntry:
 
 
 def cells(include_skips: bool = True):
-    """All 40 (arch × shape) cells with skip annotations."""
+    """All (arch × shape) cells with skip annotations."""
     out = []
     for name, entry in ARCHS.items():
         for shape in SHAPES.values():

@@ -1,8 +1,10 @@
 """Mamba-2 (SSD — state-space duality) blocks.
 
 The training/prefill path uses the chunked SSD algorithm (Dao & Gu 2024):
-within a chunk everything is batched matmuls (MXU-friendly); across chunks a
-small ``lax.scan`` carries the [H, P, N] state.  The decode path is the exact
+within a chunk everything is batched matmuls (MXU-friendly); a ``lax.scan``
+over chunks carries the [H, P, N] state and does each chunk's work, so its
+temporaries are of one chunk.  Each lowering's plan is recorded in
+``repro.obs.runtime.ssd_plans``.  The decode path is the exact
 single-step recurrence on the same state, so prefill→decode hand-off is
 bit-consistent up to float error (covered by tests against the naive
 recurrent oracle).
@@ -25,6 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.models import layers as L
+from repro.obs import runtime
 from repro.utils import ceil_to, split_keys
 
 
@@ -39,6 +42,7 @@ class SSDConfig:
     chunk: int = 128
     dt_min: float = 0.001
     dt_max: float = 0.1
+    eps: float = 1e-6             # the gated RMSNorm's
 
     @property
     def d_inner(self) -> int:
@@ -98,63 +102,58 @@ def _ssd_chunked(x, dt, A, B, C, chunk: int, h0=None):
     """Chunked SSD scan.
 
     x [B,S,H,P], dt [B,S,H] (already softplus'ed), A [H] (negative),
-    B, C [B,S,G,N].  Returns (y [B,S,H,P], h_final [B,H,P,N]).
+    B, C [B,S,G,N]; head ``h`` reads group ``h // (H/G)``.  Returns
+    (y [B,S,H,P], h_final [B,H,P,N]).
+
+    One ``lax.scan`` step per chunk of Q tokens does the chunk's whole
+    work, so every temporary is of one chunk: ``C·Bᵀ`` once per group
+    [B,G,Q,Q]; the decays and scores [B,G,H/G,Q,Q] (the largest); the
+    chunk's own output from them; the carried state's output; and the
+    state at the chunk's end.  The chunk is a tiling choice: the result
+    does not depend on it beyond rounding.
     """
     b, s, h, p = x.shape
     g, n = B.shape[-2:]
-    chunk = min(chunk, s)        # decode: no padding waste for tiny s
-    sp = ceil_to(s, chunk)
-    pad = sp - s
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    nc, q = sp // chunk, chunk
-    rep = h // g                                   # heads per router group
+    r = h // g                                     # heads per group
+    q = min(chunk, s)            # decode: no padding waste for tiny s
+    sp = ceil_to(s, q)
+    if sp != s:                  # dt 0 past the end: no decay, no input
+        pad = ((0, 0), (0, sp - s))
+        x = jnp.pad(x, pad + ((0, 0), (0, 0)))
+        dt = jnp.pad(dt, pad + ((0, 0),))
+        B = jnp.pad(B, pad + ((0, 0), (0, 0)))
+        C = jnp.pad(C, pad + ((0, 0), (0, 0)))
+    runtime.record_ssd_plan(q, h, g, b * h * q * q * 4)
+    xg = x.reshape(b, sp, g, r, p)
+    dA = (dt * A).reshape(b, sp, g, r)
+    dtg = dt.reshape(b, sp, g, r)
+    causal = jnp.tril(jnp.ones((q, q), bool))
 
-    xc = x.reshape(b, nc, q, h, p)
-    dtc = dt.reshape(b, nc, q, h)
-    Bc = jnp.repeat(B.reshape(b, nc, q, g, n), rep, axis=3)   # [B,Nc,Q,H,N]
-    Cc = jnp.repeat(C.reshape(b, nc, q, g, n), rep, axis=3)
+    def step(carry, c):
+        hprev, y = carry                           # [B,G,R,P,N], [B,Sp,G,R,P]
+        part = lambda t: lax.dynamic_slice_in_dim(t, c * q, q, axis=1)
+        xc, dtc, Bc, Cc = part(xg), part(dtg), part(B), part(C)
+        cs = jnp.cumsum(part(dA), axis=1)          # [B,Q,G,R] within chunk
+        xdt = xc * dtc[..., None]                  # [B,Q,G,R,P]
+        # L[i,j] = exp(cs_i - cs_j) for i >= j, else 0
+        seg = jnp.moveaxis(cs, 1, -1)              # [B,G,R,Q]
+        diff = seg[..., :, None] - seg[..., None, :]
+        decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))  # [B,G,R,Q,Q]
+        cb = jnp.einsum("bign,bjgn->bgij", Cc, Bc)  # once per group
+        yc = jnp.einsum("bgrij,bjgrp->bigrp", cb[:, :, None] * decay, xdt)
+        yc = yc + jnp.einsum("bign,bgrpn->bigrp", Cc, hprev) \
+            * jnp.exp(cs)[..., None]
+        last = cs[:, -1]                           # [B,G,R]
+        states = jnp.einsum("bjgn,bjgrp->bgrpn", Bc,
+                            xdt * jnp.exp(last[:, None] - cs)[..., None])
+        hnew = hprev * jnp.exp(last)[..., None, None] + states
+        return (hnew, lax.dynamic_update_slice_in_dim(y, yc, c * q, 1)), None
 
-    dA = dtc * A[None, None, None, :]              # [B,Nc,Q,H] (negative)
-    dAcs = jnp.cumsum(dA, axis=2)                  # within-chunk cumsum
-
-    # --- intra-chunk (quadratic in Q, batched matmul) -----------------
-    # L[i,j] = exp(dAcs_i − dAcs_j) for i ≥ j else 0
-    li = dAcs[:, :, :, None, :]                    # [B,Nc,Q,1,H]
-    lj = dAcs[:, :, None, :, :]                    # [B,Nc,1,Q,H]
-    mask = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None]
-    Lmat = jnp.where(mask, jnp.exp(li - lj), 0.0)  # [B,Nc,Q,Q,H]
-    scores = jnp.einsum("bcihn,bcjhn->bcijh", Cc, Bc) * Lmat
-    xdt = xc * dtc[..., None]                      # [B,Nc,Q,H,P]
-    y_intra = jnp.einsum("bcijh,bcjhp->bcihp", scores, xdt)
-
-    # --- chunk states --------------------------------------------------
-    decay_to_end = jnp.exp(dAcs[:, :, -1:, :] - dAcs)      # [B,Nc,Q,H]
-    states = jnp.einsum("bcqhn,bcqhp,bcqh->bchpn", Bc, xdt, decay_to_end)
-
-    # --- inter-chunk recurrence ----------------------------------------
-    chunk_decay = jnp.exp(dAcs[:, :, -1, :])               # [B,Nc,H]
-
-    def step(hprev, inp):
-        st, dec = inp                                       # [B,H,P,N],[B,H]
-        hnew = hprev * dec[..., None, None] + st
-        return hnew, hprev
-
-    h_init = jnp.zeros((b, h, p, n), jnp.float32) if h0 is None else h0
-    h_fin, h_prevs = lax.scan(
-        step, h_init,
-        (states.swapaxes(0, 1), chunk_decay.swapaxes(0, 1)))
-    h_prevs = h_prevs.swapaxes(0, 1)                        # [B,Nc,H,P,N]
-
-    # --- inter-chunk contribution --------------------------------------
-    in_decay = jnp.exp(dAcs)                                # [B,Nc,Q,H]
-    y_inter = jnp.einsum("bcqhn,bchpn,bcqh->bcqhp", Cc, h_prevs, in_decay)
-
-    y = (y_intra + y_inter).reshape(b, sp, h, p)[:, :s]
-    return y, h_fin
+    h_init = (jnp.zeros((b, g, r, p, n), jnp.float32) if h0 is None
+              else h0.reshape(b, g, r, p, n))
+    (h_fin, y), _ = lax.scan(step, (h_init, jnp.zeros_like(xg)),
+                             jnp.arange(sp // q))
+    return y.reshape(b, sp, h, p)[:, :s], h_fin.reshape(b, h, p, n)
 
 
 @jax.named_scope("ssd")
@@ -196,8 +195,9 @@ def ssd_block(params, x: jax.Array, cfg: SSDConfig, *,
     y, h_fin = _ssd_chunked(xs32, dt, A, B32, C32, cfg.chunk, h0=h0)
     y = y + xs32 * params["D"][None, None, :, None]
 
-    y = y.reshape(b, s, cfg.d_inner).astype(cd)
-    y = L.rmsnorm(params["norm"], y) * jax.nn.silu(zgate)
+    # Mamba-2's gated RMSNorm: gate, then normalise over d_inner
+    y = y.reshape(b, s, cfg.d_inner) * jax.nn.silu(zgate.astype(jnp.float32))
+    y = L.rmsnorm(params["norm"], y, cfg.eps).astype(cd)
     out = L.dense(params["out_proj"], y, policy=policy, bfp=bfp)
     new_state = None if state is None else {
         "h": h_fin, "conv_x": ncx, "conv_b": ncb, "conv_c": ncc}

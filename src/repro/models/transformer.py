@@ -35,7 +35,14 @@ def _norm_init(cfg: ModelConfig, d: int) -> dict:
 
 
 def _norm(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
-    return L.layernorm(p, x) if cfg.norm == "layernorm" else L.rmsnorm(p, x)
+    return (L.layernorm(p, x) if cfg.norm == "layernorm"
+            else L.rmsnorm(p, x, cfg.norm_eps))
+
+
+def _resid(cfg: ModelConfig, h: jax.Array, y: jax.Array) -> jax.Array:
+    """The residual stream plus a sublayer's output (times granite's
+    residual multiplier, where the config has one)."""
+    return h + y if cfg.resid_mult == 1.0 else h + y * cfg.resid_mult
 
 
 def _act(cfg: ModelConfig):
@@ -61,13 +68,15 @@ def attn_cfg_for(cfg: ModelConfig, spec: LayerSpec) -> L.AttnConfig:
         kv_chunk=cfg.kv_chunk,
         causal_skip=cfg.causal_skip,
         use_flash=cfg.use_flash and spec.kind == "attn",
+        scale=cfg.attn_mult,
     )
 
 
 def _ssd_cfg(cfg: ModelConfig) -> ssm.SSDConfig:
     return ssm.SSDConfig(
         d_model=cfg.d_model, d_state=cfg.ssm_state, headdim=cfg.ssm_headdim,
-        expand=cfg.ssm_expand, conv_width=cfg.conv_width, chunk=cfg.ssm_chunk)
+        expand=cfg.ssm_expand, conv_width=cfg.conv_width, chunk=cfg.ssm_chunk,
+        eps=cfg.norm_eps)
 
 
 def _lru_cfg(cfg: ModelConfig) -> hybrid.LRUConfig:
@@ -81,7 +90,8 @@ def _moe_cfg(cfg: ModelConfig) -> moe_mod.MoEConfig:
         d_model=cfg.d_model, d_ff=cfg.d_ff, n_experts=cfg.n_experts,
         top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
         group_size=cfg.moe_group_size, gated=cfg.gated_mlp,
-        shared_expert=cfg.shared_expert)
+        shared_expert=cfg.shared_expert, shared_d_ff=cfg.shared_d_ff,
+        n_routed=cfg.n_routed_experts, offset=cfg.expert_offset)
 
 
 def sinusoidal_embed(positions: jax.Array, d: int) -> jax.Array:
@@ -141,7 +151,7 @@ def _apply_mlp(p, h, spec, cfg, policy, bfp):
                                    bfp=bfp)
     if cfg.post_norm:
         y = _norm(cfg, p["mlp_post_norm"], y)
-    return h + y, aux
+    return _resid(cfg, h, y), aux
 
 
 def _sub_apply(p, h, spec, cfg, *, policy, bfp, cross_kv, positions):
@@ -155,16 +165,16 @@ def _sub_apply(p, h, spec, cfg, *, policy, bfp, cross_kv, positions):
                                   kv_x=kv, positions=positions)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
-        h = h + y
+        h = _resid(cfg, h, y)
     elif spec.kind == "ssd":
         u = _norm(cfg, p["norm"], h)
         y, _ = ssm.ssd_block(p["ssd"], u, _ssd_cfg(cfg), policy=policy, bfp=bfp)
-        h = h + y
+        h = _resid(cfg, h, y)
     elif spec.kind == "lru":
         u = _norm(cfg, p["norm"], h)
         y, _ = hybrid.lru_block(p["lru"], u, _lru_cfg(cfg), policy=policy,
                                 bfp=bfp)
-        h = h + y
+        h = _resid(cfg, h, y)
     return _apply_mlp(p, h, spec, cfg, policy, bfp)
 
 
@@ -200,6 +210,8 @@ def embed_tokens(params, cfg: ModelConfig, tokens: jax.Array,
     h = L.embed_lookup(params["embed"], tokens, policy)
     if cfg.embed_scale:
         h = h * math.sqrt(cfg.d_model)
+    if cfg.embed_mult != 1.0:
+        h = h * cfg.embed_mult
     if cfg.pos_embed == "sinusoidal":
         h = h + sinusoidal_embed(positions, cfg.d_model).astype(h.dtype)
     return h
@@ -214,8 +226,12 @@ def forward(params, cfg: ModelConfig, tokens: jax.Array, *,
     """Full-sequence forward. Returns {hidden, taps, aux, emb}.
 
     Tap memory: with ``tap_indices`` (+ ``tap_pool``) only the selected
-    superblocks' hidden states are kept, *pooled inside the scan body* into a
+    layers' hidden states are kept, *pooled inside the scan body* into a
     small carry buffer — [n_sel, B, S/pool, D] instead of [n_rep, B, S, D].
+    A tap index names a layer of the scanned stack (period ``p``, rep
+    ``r``, sublayer ``i``: layer ``r·p + i``), so a period of several
+    layers is tapped inside.  Without ``tap_indices`` the taps are each
+    period's output.
     At pod scale this is the difference between 0.5 GB and 85 GB of tap
     residuals per device (DESIGN.md §3).
     """
@@ -238,6 +254,10 @@ def forward(params, cfg: ModelConfig, tokens: jax.Array, *,
             tap_buf0 = jnp.zeros((len(tap_indices), b, sp, cfg.d_model),
                                  h.dtype)
 
+        period = len(cfg.pattern)
+        # the sublayers of a period that some tap index names
+        tapped = {int(t) % period for t in tap_indices} if use_buf else ()
+
         def body(carry, xs):
             if use_buf:
                 (h, aux, buf), (p_rep, step_i) = carry, xs
@@ -249,10 +269,12 @@ def forward(params, cfg: ModelConfig, tokens: jax.Array, *,
                                   positions=positions)
                 h = constrain(h, "resid")
                 aux = aux + a
+                if i in tapped:
+                    layer = step_i if period == 1 else step_i * period + i
+                    pooled = pool_seq(h, tap_pool)
+                    match = (idx == layer)[:, None, None, None]
+                    buf = jnp.where(match, pooled[None], buf)
             if use_buf:
-                pooled = pool_seq(h, tap_pool)
-                match = (idx == step_i)[:, None, None, None]
-                buf = jnp.where(match, pooled[None], buf)
                 return (h, aux, buf), None
             return (h, aux), (h if collect_taps else jnp.zeros((), h.dtype))
 
@@ -275,8 +297,9 @@ def forward(params, cfg: ModelConfig, tokens: jax.Array, *,
 
 def lm_logits(params, cfg: ModelConfig, hidden: jax.Array,
               policy: L.Policy = L.Policy()) -> jax.Array:
-    return L.unembed_logits(params["embed"], hidden, cfg.vocab, policy,
-                            softcap=cfg.softcap_final)
+    logits = L.unembed_logits(params["embed"], hidden, cfg.vocab, policy,
+                              softcap=cfg.softcap_final)
+    return logits if cfg.logits_div == 1.0 else logits / cfg.logits_div
 
 
 # --------------------------------------------------------------------------
@@ -355,15 +378,17 @@ def _sub_prefill(p, h, spec, cfg, *, policy, cross_kv, positions, max_len,
                                           window=acfg.window,
                                           q_chunk=acfg.q_chunk,
                                           kv_chunk=acfg.kv_chunk,
-                                          causal_skip=acfg.causal_skip)
+                                          causal_skip=acfg.causal_skip,
+                                          scale=acfg.scale)
             else:
                 o = L.full_attention(q, k, v, causal=acfg.causal,
-                                     softcap=acfg.softcap, window=acfg.window)
+                                     softcap=acfg.softcap, window=acfg.window,
+                                     scale=acfg.scale)
             o = o.reshape(b, s, acfg.n_heads * acfg.head_dim)
             y = L.dense(p["attn"]["wo"], o, policy=policy)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
-        h = h + y
+        h = _resid(cfg, h, y)
         size = _ring_size(cfg, spec, max_len)
         with jax.named_scope("kv_cache"):
             if spec.kind == "local" and size < max_len:
@@ -393,7 +418,7 @@ def _sub_prefill(p, h, spec, cfg, *, policy, cross_kv, positions, max_len,
                                   kv_x=cross_kv, positions=positions)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
-        h = h + y
+        h = _resid(cfg, h, y)
         skv = cross_kv.shape[1]
         with jax.named_scope("kv_cache"):
             k = L.dense(p["attn"]["wk"], cross_kv, policy=policy).reshape(
@@ -408,7 +433,7 @@ def _sub_prefill(p, h, spec, cfg, *, policy, cross_kv, positions, max_len,
         c = _ssd_cfg(cfg)
         y, st = ssm.ssd_block(p["ssd"], u, c, policy=policy,
                               state=ssm.ssd_state_init(c, b, dtype))
-        h = h + y
+        h = _resid(cfg, h, y)
         h, _ = _apply_mlp(p, h, spec, cfg, policy, L.NO_BFP)
         return h, st
 
@@ -417,7 +442,7 @@ def _sub_prefill(p, h, spec, cfg, *, policy, cross_kv, positions, max_len,
         c = _lru_cfg(cfg)
         y, st = hybrid.lru_block(p["lru"], u, c, policy=policy,
                                  state=hybrid.lru_state_init(c, b, dtype))
-        h = h + y
+        h = _resid(cfg, h, y)
         h, _ = _apply_mlp(p, h, spec, cfg, policy, L.NO_BFP)
         return h, st
     raise ValueError(spec.kind)
@@ -485,7 +510,7 @@ def _sub_decode(p, h, spec, cfg, cache, *, policy):
                                                   policy=policy)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
-        h = h + y
+        h = _resid(cfg, h, y)
         h, _ = _apply_mlp(p, h, spec, cfg, policy, L.NO_BFP)
         return h, new_cache
     if spec.kind == "cross":
@@ -494,27 +519,27 @@ def _sub_decode(p, h, spec, cfg, cache, *, policy):
             q = L.dense(p["attn"]["wq"], u, policy=policy).reshape(
                 b, 1, cfg.n_heads, cfg.head_dim)
             o = L.full_attention(q, cache["k"], cache["v"], causal=False,
-                                 softcap=acfg.softcap)
+                                 softcap=acfg.softcap, scale=acfg.scale)
             y = L.dense(p["attn"]["wo"],
                         o.reshape(b, 1, cfg.n_heads * cfg.head_dim),
                         policy=policy)
         if cfg.post_norm:
             y = _norm(cfg, p["post_norm"], y)
-        h = h + y
+        h = _resid(cfg, h, y)
         h, _ = _apply_mlp(p, h, spec, cfg, policy, L.NO_BFP)
         return h, cache
     if spec.kind == "ssd":
         u = _norm(cfg, p["norm"], h)
         y, st = ssm.ssd_block(p["ssd"], u, _ssd_cfg(cfg), policy=policy,
                               state=cache)
-        h = h + y
+        h = _resid(cfg, h, y)
         h, _ = _apply_mlp(p, h, spec, cfg, policy, L.NO_BFP)
         return h, st
     if spec.kind == "lru":
         u = _norm(cfg, p["norm"], h)
         y, st = hybrid.lru_block(p["lru"], u, _lru_cfg(cfg), policy=policy,
                                  state=cache)
-        h = h + y
+        h = _resid(cfg, h, y)
         h, _ = _apply_mlp(p, h, spec, cfg, policy, L.NO_BFP)
         return h, st
     raise ValueError(spec.kind)
@@ -538,7 +563,8 @@ def _ring_decode(p_attn, u, cache, acfg: L.AttnConfig, cfg: ModelConfig,
             cache["pos"], cur[None].astype(jnp.int32), slot, axis=0)
     g = acfg.n_heads // acfg.n_kv
     scores = L._softcap(
-        L._gqa_scores(q, L.expand_kv(kc, g)) / math.sqrt(acfg.head_dim),
+        L._scaled(L._gqa_scores(q, L.expand_kv(kc, g)), acfg.head_dim,
+                  acfg.scale),
         acfg.softcap)
     scores = constrain(scores, "dec_scores")   # keep ring cache seq-sharded
     valid = (pos >= 0) & (pos <= cur) & (pos > cur - (acfg.window or size))

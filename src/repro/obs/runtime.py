@@ -1,6 +1,6 @@
 """Counters of the chip path, held in this process's memory.
 
-Three :class:`~repro.obs.recorder.SpanRecorder` instances, one per process:
+Four :class:`~repro.obs.recorder.SpanRecorder` instances, one per process:
 
 - :data:`compiles`: a span of kind ``compile`` for each of JAX's compile
   events (tracing to a jaxpr, lowering, and the backend compile, which also
@@ -18,7 +18,12 @@ Three :class:`~repro.obs.recorder.SpanRecorder` instances, one per process:
   while a program is traced: named by the route (``index`` or
   ``onehot``), with the group size, experts and capacity in its args.  A
   program runs the route it was lowered with, so :func:`moe_routes_of`
-  says which route each step program took.
+  says which route each step program took.  The args also hold the
+  router's width (``routed``) and the first held expert (``offset``).
+- :data:`ssd_plans`: the newest :data:`MAXLEN` SSD mixer lowerings
+  (``repro.models.ssm``), named ``ssd``: chunk, heads, groups of B and C,
+  and the bytes of the largest temporary of one chunk; read per program
+  by :func:`ssd_plans_of`.
 
 Stamps (span ``t0``/``t1``, sample ``t``) are realtime nanoseconds, the
 profiler's clock: a trace's event times are nanoseconds after its
@@ -43,6 +48,7 @@ COMPILE_EVENTS = {
 compiles = SpanRecorder()
 data = SpanRecorder(maxlen=MAXLEN)
 moe_routes = SpanRecorder(maxlen=MAXLEN)
+ssd_plans = SpanRecorder(maxlen=MAXLEN)
 
 _installed = False
 _lock = threading.Lock()
@@ -77,17 +83,40 @@ def backend_compiles(fun_name: str) -> int:
 
 
 def record_moe_route(route: str, group: int, experts: int,
-                     capacity: int) -> None:
-    """Record one MoE layer's lowering; called while a program is traced."""
+                     capacity: int, routed: int | None = None,
+                     offset: int = 0) -> None:
+    """Record one MoE layer's lowering; called while a program is traced.
+    ``experts`` are those the layer holds, from router output ``offset``
+    on, of the ``routed`` the router scores (``experts`` when None)."""
     t = int(time.time() * 1e9)        # the clock of JAX's compile events
     moe_routes.span("lowering", route, t, t, group=group, experts=experts,
-                    capacity=capacity)
+                    capacity=capacity, routed=routed or experts,
+                    offset=offset)
+
+
+def record_ssd_plan(chunk: int, heads: int, groups: int,
+                    temp_bytes: int) -> None:
+    """Record one SSD mixer's lowering; called while a program is traced:
+    its chunk, heads and groups of B and C, and the bytes of its largest
+    temporary of one chunk."""
+    t = int(time.time() * 1e9)
+    ssd_plans.span("lowering", "ssd", t, t, chunk=chunk, heads=heads,
+                   groups=groups, temp_bytes=temp_bytes)
+
+
+def _lowered_in(recorder: SpanRecorder, fun_name: str) -> list:
+    traces = [(s.t0, s.t1) for s in compiles.spans_of("compile")
+              if s.name == "trace" and s.args.get("fun_name") == fun_name]
+    return [r for r in recorder.spans
+            if any(a <= r.t0 <= b for a, b in traces)]
 
 
 def moe_routes_of(fun_name: str) -> list:
     """The MoE lowerings recorded while the jitted function ``fun_name``
     was traced, in order (its ``trace`` compile spans hold them)."""
-    traces = [(s.t0, s.t1) for s in compiles.spans_of("compile")
-              if s.name == "trace" and s.args.get("fun_name") == fun_name]
-    return [r for r in moe_routes.spans
-            if any(a <= r.t0 <= b for a, b in traces)]
+    return _lowered_in(moe_routes, fun_name)
+
+
+def ssd_plans_of(fun_name: str) -> list:
+    """The SSD lowerings recorded while ``fun_name`` was traced, in order."""
+    return _lowered_in(ssd_plans, fun_name)

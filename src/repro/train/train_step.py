@@ -43,11 +43,16 @@ class TrainConfig:
     backbone_dtype: jnp.dtype = jnp.bfloat16   # frozen storage precision
 
 
-def tap_indices(n_rep: int, n_blocks: int) -> np.ndarray:
-    """Evenly spaced backbone superblocks feeding the branch blocks."""
-    if n_rep <= 0:
-        raise ValueError("backbone has no scanned blocks to tap")
-    return np.round(np.linspace(0, n_rep - 1, n_blocks)).astype(np.int32)
+def tap_indices(n_layers: int, n_blocks: int) -> np.ndarray:
+    """Evenly spaced layers of the scanned stack feeding the branch blocks."""
+    if n_layers <= 0:
+        raise ValueError("backbone has no scanned layers to tap")
+    return np.round(np.linspace(0, n_layers - 1, n_blocks)).astype(np.int32)
+
+
+def tapped_layers(cfg: ModelConfig) -> int:
+    """Layers of the scanned stack, which the branch may tap."""
+    return cfg.n_rep * len(cfg.pattern)
 
 
 def init_state(key: jax.Array, entry, cfg: ModelConfig, tcfg: TrainConfig,
@@ -86,8 +91,7 @@ def make_train_step(entry, cfg: ModelConfig, tcfg: TrainConfig,
     module = entry.module
 
     if tcfg.mode == "duplex":
-        n_rep = cfg.n_rep
-        idx = tap_indices(n_rep, tcfg.duplex.n_blocks)
+        idx = tap_indices(tapped_layers(cfg), tcfg.duplex.n_blocks)
 
         def loss_fn(branch, backbone, batch):
             fe = batch.get("frontend")

@@ -32,6 +32,9 @@ EXPECTED = {
     ("granite-3-8b", "train"): TRAIN + ("mlp",),
     ("granite-3-8b", "prefill"): SERVE + ("mlp",),
     ("granite-3-8b", "decode"): SERVE + ("mlp",),
+    ("granite-4.0-h-small", "train"): TRAIN + MOE + ("mlp", "ssd"),
+    ("granite-4.0-h-small", "prefill"): SERVE + MOE + ("mlp", "ssd"),
+    ("granite-4.0-h-small", "decode"): SERVE + MOE + ("mlp", "ssd"),
 }
 # recurrent mixers, each in the forward of the preset that has it
 MIXERS = {"mamba2-780m": "ssd", "recurrentgemma-9b": "lru"}
@@ -345,3 +348,52 @@ def test_launchers_mark_each_step(monkeypatch, tmp_path, launcher, name):
                    for p in _xplane(trace_dir).planes if p.name == "/host:CPU"
                    for line in p.lines for e in line.events if e.name == name)
     assert steps == [0, 1]
+
+
+@pytest.mark.parametrize("program", ["train", "prefill", "decode"])
+def test_hybrid_records_its_ssd_plans_and_expert_share(program):
+    """Each SSD mixer of a lowering records its chunk, heads, groups and
+    largest per-chunk temporary; each MoE layer the experts it holds of
+    the router's.  The hybrid smoke preset at batch 2 x 32: nine SSD
+    layers and ten MoE layers a program."""
+    cfg = registry.get("granite-4.0-h-small").config("smoke")
+    t0 = time.time_ns()
+    fn, args = step_programs("granite-4.0-h-small")[program]
+    fn.lower(*args)
+    name = f"{program}_step"
+    plans = [r for r in runtime.ssd_plans_of(name) if r.t0 >= t0]
+    q = 1 if program == "decode" else cfg.ssm_chunk
+    heads = cfg.ssm_expand * cfg.d_model // cfg.ssm_headdim
+    assert len(plans) == 9 and {r.name for r in plans} == {"ssd"}
+    assert all(r.args == {"chunk": q, "heads": heads, "groups": 1,
+                          "temp_bytes": 2 * heads * q * q * 4} for r in plans)
+    routes = [r for r in runtime.moe_routes_of(name) if r.t0 >= t0]
+    assert len(routes) == 10 and all(
+        (r.args["experts"], r.args["routed"], r.args["offset"])
+        == (cfg.n_experts, cfg.n_routed_experts, cfg.expert_offset)
+        for r in routes)
+
+
+def test_hybrid_ssd_temporaries_at_the_benchmark_size():
+    """The benchmark's granite-4.0-h stage at 8 x 4096, lowered (not
+    compiled): every SSD mixer's largest per-chunk temporary is one 256
+    -token chunk's [8, 128, 256, 256] float32, 256 MiB, where building all
+    16 chunks' decays at once took 4 GiB."""
+    cfg = dataclasses.replace(
+        registry.get("granite-4.0-h-small").config("full"), n_layers=10,
+        n_experts=9, n_routed_experts=72, vocab=12_544)
+    entry = registry.get("granite-4.0-h-small")
+    policy = L.Policy(compute_dtype=jnp.bfloat16)
+    tcfg = duplex_tcfg(cfg)
+    state = jax.eval_shape(
+        lambda k: ts.init_state(k, entry, cfg, tcfg, policy),
+        jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((8, 4096), jnp.int32)
+    t0 = time.time_ns()
+    jax.jit(ts.make_train_step(entry, cfg, tcfg, policy)).lower(
+        state, {"tokens": toks, "labels": toks})
+    plans = [r.args for r in runtime.ssd_plans_of("train_step")
+             if r.t0 >= t0]
+    assert plans == [{"chunk": 256, "heads": 128, "groups": 1,
+                      "temp_bytes": 8 * 128 * 256 * 256 * 4}] * 9
+    assert tcfg.duplex.n_blocks == 8

@@ -1,4 +1,6 @@
 """MoE / SSD / RG-LRU layers vs naive oracles; prefill↔decode consistency."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,6 +33,81 @@ def test_ssd_chunked_matches_recurrent_oracle(chunk):
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(hf_got), np.asarray(hf_want),
                                rtol=1e-4, atol=1e-4)
+
+
+# (groups, length, chunk): lengths that are and are not a multiple of it
+SSD_CASES = [(1, 32, 8), (1, 30, 8), (2, 30, 8), (1, 30, 16), (2, 45, 16),
+             (1, 5, 16)]
+
+
+@pytest.mark.parametrize("g,s,chunk", SSD_CASES)
+@pytest.mark.parametrize("carried", [False, True])
+def test_ssd_chunked_cases(g, s, chunk, carried):
+    """The chunked scan against the recurrence with one or two groups of B
+    and C, ragged lengths, two chunk sizes, and a state carried in from 11
+    tokens before (``h0``)."""
+    x, dt, A, B, C = _ssd_inputs(key=7, s=s + 11, g=g)
+    want, hf_want = ssm.ssd_reference(x, dt, A, B, C)
+    if carried:
+        _, h0 = ssm.ssd_reference(*(a[:, :11] for a in (x, dt)), A,
+                                  *(a[:, :11] for a in (B, C)))
+        rest = [a[:, 11:] for a in (x, dt, B, C)]
+        got, hf_got = ssm._ssd_chunked(*rest[:2], A, *rest[2:], chunk, h0=h0)
+        want = want[:, 11:]
+    else:
+        got, hf_got = ssm._ssd_chunked(x, dt, A, B, C, chunk)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(hf_got, hf_want, rtol=1e-4, atol=1e-4)
+
+
+def test_ssd_chunk_is_a_tiling_choice():
+    x, dt, A, B, C = _ssd_inputs(key=9, s=48, g=1)
+    outs = [ssm._ssd_chunked(x, dt, A, B, C, c) for c in (8, 16, 48)]
+    for y, h in outs[1:]:
+        np.testing.assert_allclose(y, outs[0][0], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(h, outs[0][1], rtol=1e-5, atol=1e-5)
+
+
+def test_ssd_plan_records_the_chunk_temporary():
+    runtime.record_compiles()
+    x, dt, A, B, C = _ssd_inputs(s=40, g=1)
+
+    def ssd_plan_probe(x):
+        return ssm._ssd_chunked(x, dt, A, B, C, 16)[0]
+
+    jax.jit(ssd_plan_probe).lower(x)
+    r = runtime.ssd_plans_of("ssd_plan_probe")[-1]
+    b, _, h, _ = x.shape
+    assert r.args == {"chunk": 16, "heads": h, "groups": 1,
+                      "temp_bytes": b * h * 16 * 16 * 4}
+
+
+def test_ssd_block_gates_then_normalises():
+    """Mamba-2's mixer, written out: x, B, C through the causal conv and
+    SiLU, dt = softplus(dt + bias), the SSD, y + D·x, then the gated
+    RMSNorm rmsnorm(y · silu(z)) · scale over d_inner, then out_proj."""
+    cfg = ssm.SSDConfig(d_model=16, d_state=8, headdim=4, expand=2, chunk=4,
+                        eps=1e-5)
+    params = ssm.ssd_init(jax.random.PRNGKey(21), cfg)
+    params["norm"]["scale"] = jax.random.uniform(jax.random.PRNGKey(22),
+                                                 (cfg.d_inner,)) + 0.5
+    u = jax.random.normal(jax.random.PRNGKey(23), (2, 12, 16))
+    y, _ = ssm.ssd_block(params, u, cfg, policy=P32)
+
+    def conv(name, v):
+        return ssm._causal_conv(v, params[name]["w"], params[name]["b"])[0]
+
+    z = u @ params["z_proj"]["w"]
+    xs = conv("conv_x", u @ params["x_proj"]["w"]).reshape(2, 12, -1, 4)
+    B = conv("conv_b", u @ params["b_proj"]["w"])[:, :, None]
+    C = conv("conv_c", u @ params["c_proj"]["w"])[:, :, None]
+    dt = jax.nn.softplus(u @ params["dt_proj"]["w"] + params["dt_bias"])
+    ys, _ = ssm.ssd_reference(xs, dt, -jnp.exp(params["A_log"]), B, C)
+    ys = (ys + xs * params["D"][:, None]).reshape(2, 12, -1)
+    g = ys * jax.nn.silu(z)
+    g = g / jnp.sqrt(jnp.mean(g * g, -1, keepdims=True) + 1e-5)
+    want = (g * params["norm"]["scale"]) @ params["out_proj"]["w"]
+    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-4)
 
 
 def test_ssd_prefill_then_decode_consistent():
@@ -265,3 +342,53 @@ def test_moe_apply_routes_by_group_size_and_records_it():
     assert [s[:2] for s in seen] == [("onehot", n - 1), ("index", n),
                                      ("index", n)]
     assert all(s[2:] == (4, moe.capacity(cfg, s[1])) for s in seen)
+
+
+@pytest.mark.parametrize("cf", [100.0, 1.0])
+@pytest.mark.parametrize("route", ["onehot", "index"])
+def test_moe_shares_add_up_to_the_whole_layer(route, cf):
+    """Eight layers that each hold 2 of a router's 16 experts (its share of
+    an expert-parallel group), with the shared expert counted once, give
+    the whole layer's output: the same choices kept past capacity, the
+    gates normalised over all of them."""
+    e, k, held = 16, 4, 2
+    whole = moe.MoEConfig(d_model=8, d_ff=16, n_experts=e, top_k=k,
+                          capacity_factor=cf, group_size=32,
+                          shared_expert=True, shared_d_ff=24)
+    params = moe.moe_init(jax.random.PRNGKey(30), whole)
+    x = jax.random.normal(jax.random.PRNGKey(31), (2, 32, 8))
+
+    def apply(cfg, p):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(moe, "_INDEX_ROUTE_MIN_GROUP", 32 if route == "index"
+                       else 10**9)
+            return moe.moe_apply(p, x, cfg, policy=P32)[0]
+
+    want = apply(whole, params)
+    parts = L.mlp(params["shared"], x, policy=P32)
+    for i in range(e // held):
+        share = dataclasses.replace(whole, n_experts=held, n_routed=e,
+                                    offset=held * i, shared_expert=False)
+        p = {"router": params["router"]}
+        p.update({n: params[n][held * i:held * (i + 1)]
+                  for n in ("wi", "wg", "wo")})
+        parts = parts + apply(share, p)
+    np.testing.assert_allclose(parts, want, rtol=1e-5, atol=1e-5)
+
+
+def test_moe_records_the_experts_held():
+    runtime.record_compiles()
+    cfg = moe.MoEConfig(d_model=8, d_ff=16, n_experts=3, top_k=2, n_routed=12,
+                        offset=6, group_size=16)
+    params = moe.moe_init(jax.random.PRNGKey(5), cfg)
+    assert params["router"]["w"].shape == (8, 12)
+    assert params["wi"].shape == (3, 8, 16)
+
+    def moe_share_probe(x):
+        return moe.moe_apply(params, x, cfg, policy=P32)[0]
+
+    jax.jit(moe_share_probe).lower(jnp.ones((1, 16, 8)))
+    r = runtime.moe_routes_of("moe_share_probe")[-1]
+    assert (r.args["experts"], r.args["routed"], r.args["offset"],
+            r.args["capacity"]) == (3, 12, 6, moe.capacity(cfg, 16))
+    assert moe.capacity(cfg, 16) == 4       # 16 tokens · 2 / 12 experts
